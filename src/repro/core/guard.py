@@ -195,7 +195,7 @@ class GuardedExecutor:
                  qm: Optional[pipe.QuantizedModel] = None,
                  n_i: int = 16, n_l: int = 32,
                  block_h: Optional[int] = None,
-                 interpret: Optional[bool] = True,
+                 interpret: Optional[bool] = None,
                  faults: Optional[Dict] = None,
                  checkpoints=None,
                  registry: Optional[tele.MetricsRegistry] = None,

@@ -352,6 +352,32 @@ def _apply_arg_faults(h: jnp.ndarray, entry) -> jnp.ndarray:
     return flat.reshape(h.shape)
 
 
+def _quantize_input(qm: QuantizedModel, x_float: jnp.ndarray) -> jnp.ndarray:
+    """Network ingress: float NCHW input -> int8 at the input's
+    fixed-point position, NHWC (the single ingress layout change)."""
+    scale = 2.0 ** qm.input_m
+    h = jnp.clip(jnp.round(x_float * scale), -128, 127).astype(jnp.int8)
+    if h.ndim == 4:
+        h = jnp.transpose(h, (0, 2, 3, 1))
+    return h
+
+
+def _int8_output(h: jnp.ndarray) -> jnp.ndarray:
+    """The network's int8 output in the graph's layout (NHWC -> NCHW
+    when it is spatial: the single egress layout change)."""
+    return jnp.transpose(h, (0, 3, 1, 2)) if h.ndim == 4 else h
+
+
+def _dequantize_output(qm: QuantizedModel, h: jnp.ndarray) -> jnp.ndarray:
+    """Network egress: int8 output -> float logits at the output's
+    fixed-point position (then the output stage's fused softmax)."""
+    logits = _int8_output(h).astype(jnp.float32) * (2.0 ** -qm.output_m)
+    out_stage = qm.parsed.stage_producing(qm.parsed.output_name)
+    if out_stage is not None and out_stage.softmax:
+        logits = jax.nn.softmax(logits, axis=-1)
+    return logits
+
+
 def make_executor(qm: QuantizedModel, n_i: int = 16, n_l: int = 32,
                   block_h: Optional[int] = None,
                   interpret: Optional[bool] = None,
@@ -463,7 +489,6 @@ def make_executor(qm: QuantizedModel, n_i: int = 16, n_l: int = 32,
     stages = qm.layers
     out_name = qm.parsed.output_name
     in_name = qm.parsed.input_name
-    out_stage = qm.parsed.stage_producing(out_name)
 
     last_use: Dict[str, int] = {}
     for idx, ql in enumerate(stages):
@@ -678,13 +703,7 @@ def make_executor(qm: QuantizedModel, n_i: int = 16, n_l: int = 32,
                 ckpts[li.name] = dict(env)
 
     def _egress(env: Dict[str, jnp.ndarray]) -> jnp.ndarray:
-        h = env[out_name]
-        if h.ndim == 4:
-            h = jnp.transpose(h, (0, 3, 1, 2))      # single egress NHWC->NCHW
-        logits = h.astype(jnp.float32) * (2.0 ** -qm.output_m)
-        if out_stage is not None and out_stage.softmax:
-            logits = jax.nn.softmax(logits, axis=-1)
-        return logits
+        return _dequantize_output(qm, env[out_name])
 
     def _run(env: Dict[str, jnp.ndarray], weights, payload, start: int):
         stats: Dict[str, jnp.ndarray] = {}
@@ -694,10 +713,7 @@ def make_executor(qm: QuantizedModel, n_i: int = 16, n_l: int = 32,
         return _egress(env), stats, ckpts
 
     def _ingress(x_float: jnp.ndarray, payload) -> jnp.ndarray:
-        scale = 2.0 ** qm.input_m
-        h = jnp.clip(jnp.round(x_float * scale), -128, 127).astype(jnp.int8)
-        if h.ndim == 4:
-            h = jnp.transpose(h, (0, 2, 3, 1))      # single ingress NCHW->NHWC
+        h = _quantize_input(qm, x_float)
         if faults and in_name in faults:
             h = _apply_tensor_faults(h, faults[in_name])
         if in_name in fault_arg_set:
@@ -794,6 +810,69 @@ def run_int8(qm: QuantizedModel, x_float: jnp.ndarray,
         ex = qm._executors[key] = make_executor(
             qm, n_i, n_l, block_h=block_h, interpret=interpret)
     return ex(x_float)
+
+
+def oracle_replay(qm: QuantizedModel, x_float: jnp.ndarray,
+                  dequantize: bool = True) -> jnp.ndarray:
+    """Stage-by-stage replay of the quantized program on the
+    ``kernels/ref.py`` oracles — plain XLA ops, no Pallas kernel — the
+    bit-exact reference for the fused executor on any backend.
+
+    Covers every stage kind: conv (dense, grouped, depthwise) with its
+    fused residual add and max-pool, pool, FC, add and concat.  A fused
+    merge replays as the unfused program it stands for (conv requant,
+    then the add's alignment and requant, then the pool); a fused
+    concat's producers leave their own tensors, which the concat stage
+    aligns, merges and pools.  Returns what the executor returns
+    (float logits), or with ``dequantize=False`` the int8 output tensor
+    in the graph's layout.  Wrap it in ``jax.jit`` to run it as one
+    program."""
+    ref = ops.ref
+    env: Dict[str, jnp.ndarray] = {
+        qm.parsed.input_name: _quantize_input(qm, x_float)}
+
+    def _maxpool(h, pool_stage):
+        if pool_stage is None:
+            return h
+        return ref.maxpool2d_ref(h, pool_stage.kernel_shape[0],
+                                 pool_stage.strides[0])
+
+    for ql in qm.layers:
+        li = ql.info
+        x = env[li.inputs[0]]
+        if li.kind == P.CONV:
+            p = li.pads
+            x = jnp.pad(x, ((0, 0), (p[0], p[2]), (p[1], p[3]), (0, 0)))
+            h = ref.qconv2d_ref(x, ql.w_q, ql.b_q, li.strides,
+                                ql.spec.requant_shift, li.relu,
+                                groups=li.group)
+            if li.merge is not None:
+                h = ref.qadd_ref([h, env[li.skip_input]], ql.operand_shifts,
+                                 ql.merge_spec.requant_shift, li.merge.relu)
+            h = _maxpool(h, li.pool)
+        elif li.kind == P.POOL:
+            pool_fn = (ops.avgpool2d_nhwc if li.pool_type == "avg"
+                       else ops.maxpool2d_nhwc)
+            h = pool_fn(x, li.kernel_shape[0], li.strides[0], li.pads)
+        elif li.kind == P.FC:
+            if x.ndim > 2:
+                x = x.reshape(x.shape[0], -1)
+            h = ref.qgemm_ref(x, ql.w_q, ql.b_q, ql.spec.requant_shift,
+                              li.relu)
+        elif li.kind == P.ADD:
+            h = ref.qadd_ref([env[t] for t in li.inputs], ql.operand_shifts,
+                             ql.spec.requant_shift, li.relu)
+        elif li.kind == P.CONCAT:
+            xs = [env[t] for t in li.inputs]
+            h = ref.qconcat_ref(xs, ql.operand_shifts,
+                                axis=_concat_axis(li.axis, xs[0].ndim),
+                                relu=li.relu)
+            h = _maxpool(h, li.pool)
+        else:  # pragma: no cover - parser only emits the five kinds
+            raise ValueError(li.kind)
+        env[li.output] = h
+    out = env[qm.parsed.output_name]
+    return _dequantize_output(qm, out) if dequantize else _int8_output(out)
 
 
 def layer_bytes(li: P.LayerInfo) -> Tuple[int, int, int]:

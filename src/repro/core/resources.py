@@ -128,7 +128,10 @@ def conv_band_working_set(layers, n_l: int,
     (``block_cout = 8 * N_l``) and ``n_i`` to the dense kernel's Cin
     contraction tile (``block_cin = 8 * N_i``; ``None`` scores the
     whole-Cin contraction); ``block_h=None`` scores the untiled
-    whole-plane kernel.  Beyond dense convs the feasibility rule covers:
+    whole-plane kernel.  The kernels round a channel tile narrower than
+    its dim up to whole 128-lane tiles, and so does this charge: every
+    ``N_i``/``N_l`` below 16 builds, and scores, the kernel of 16
+    (DESIGN.md §7).  Beyond dense convs the feasibility rule covers:
 
       * dense convs with a fused residual merge — the conv band plus
         the ``skip_vmem_bytes`` band the epilogue holds alongside it;

@@ -246,7 +246,7 @@ def run_campaign(gate, x, *, trials: int = 100, flips: int = 1,
                  checkpoints: int = 2, chunk: int = 32,
                  n_i: int = 16, n_l: int = 32,
                  block_h: Optional[int] = None,
-                 interpret: Optional[bool] = True) -> Campaign:
+                 interpret: Optional[bool] = None) -> Campaign:
     """Run one vectorized SER campaign: ``trials`` sampled
     ``flips``-fault plans through a single compiled executor.
 

@@ -9,14 +9,15 @@
     y    = run(x)                                   # inference
     rep  = gate.latency_report("ARRIA10", *fit.best)  # Table-1 model
 
-Modes:
-  * ``emulation``  — CPU compile (seconds), Pallas kernels in interpret
-    mode; functional verification exactly like the paper's OpenCL
-    emulator (the paper stresses this loop: verify before the 10-hour
-    synthesis).
+Modes (kernels run in Pallas interpret mode only off a TPU, as
+``ops.default_interpret()`` decides):
+  * ``emulation``  — the jitted executor, compiled at its first call;
+    off a TPU this is functional verification exactly like the paper's
+    OpenCL emulator (the paper stresses this loop: verify before the
+    10-hour synthesis).
   * ``fullflow``   — AOT ``jit(...).lower().compile()`` of the pipeline:
     the TPU-target "synthesis".  On a TPU machine this produces the real
-    executable; here it produces the compiled CPU artifact and the
+    executable; elsewhere it produces the compiled CPU artifact and the
     resource report (our stand-in for the bitstream + fitter report).
 """
 from __future__ import annotations
@@ -38,6 +39,15 @@ from .quantize import (MAX_SHIFT, QuantSpec, best_pow2_exponent,
                        best_pow2_exponents_per_channel)
 from .resources import (FPGA_BOARDS, fpga_layer_time_s)
 from .spaces import CNNDesignSpace
+
+
+def _softmax_input(graph: Graph, tensor: str) -> str:
+    """The tensor read by the Softmax node that ``tensor`` comes from
+    (through any pass-through nodes the parser fused after it)."""
+    node = graph.producer_of(tensor)
+    while node.op_type != "Softmax":
+        node = graph.producer_of(node.inputs[0])
+    return node.inputs[0]
 
 
 @dataclasses.dataclass
@@ -147,6 +157,12 @@ class CNN2Gate:
         # intermediate tensor — it lives on in li.merge.inputs)
         desired: Dict[str, int] = {}
         for li in pm.layers:
+            if li.softmax:
+                # the int8 stage output holds the logits the fused
+                # softmax reads (the egress applies it): scale from the
+                # logits, not from the probabilities in [0, 1]
+                desired[li.output] = best_pow2_exponent(
+                    acts[_softmax_input(pm.graph, li.output)])
             tensors = list(li.inputs) + [li.output]
             if li.merge is not None:
                 tensors += list(li.merge.inputs) + [li.merge.output]
@@ -288,23 +304,26 @@ class CNN2Gate:
               ) -> Callable[[jnp.ndarray], jnp.ndarray]:
         """Return the whole-network fused executor: ONE jitted closure
         over the staged layer list (no per-call Python layer dispatch).
+        Kernels interpret only where the backend is not a TPU
+        (``ops.default_interpret()``).
 
-        emulation: interpret-mode kernels (fast CPU verify).
-        fullflow : AOT-compiled executable for the default backend (the
-        TPU-target synthesis path; identical numerics).
+        emulation: the jitted executor, compiled at its first call.
+        fullflow : AOT-compiled at the graph's input shape before it
+        returns (the TPU-target synthesis path; identical numerics);
+        ``synthesis_time_s`` is that compile.  jit's executable cache
+        holds the result, so a call at that shape compiles nothing
+        more; another batch compiles at its first call.
         """
         if self.quantized is None:
             raise RuntimeError("apply_quantization() or "
                                "calibrate_quantization() first")
         qm = self.quantized
         if mode == "emulation":
-            return pipe.make_executor(qm, n_i, n_l, block_h=block_h,
-                                      interpret=True)
+            return pipe.make_executor(qm, n_i, n_l, block_h=block_h)
         if mode == "fullflow":
-            interpret = jax.default_backend() != "tpu"
-            jitted = pipe.make_executor(qm, n_i, n_l, block_h=block_h,
-                                        interpret=interpret)
-            sample = jnp.zeros((1,) + self.parsed.input_shape[1:], jnp.float32)
+            jitted = pipe.make_executor(qm, n_i, n_l, block_h=block_h)
+            sample = jax.ShapeDtypeStruct(tuple(self.parsed.input_shape),
+                                          jnp.float32)
             t0 = time.perf_counter()
             compiled = jitted.lower(sample).compile()  # the "synthesis"
             self.synthesis_time_s = time.perf_counter() - t0
@@ -332,24 +351,21 @@ class CNN2Gate:
         ``qm``/``faults`` deploy a fault-injected program under the
         guard (defaults: the golden program, no faults);
         ``checkpoints`` (an int K or explicit boundary indices) arms
-        the stage-boundary recovery rung (DESIGN.md §11)."""
+        the stage-boundary recovery rung (DESIGN.md §11).  Whatever the
+        ``mode``, kernels interpret only off a TPU."""
         if self.quantized is None:
             raise RuntimeError("apply_quantization() or "
                                "calibrate_quantization() first")
-        interpret = (True if mode == "emulation"
-                     else jax.default_backend() != "tpu")
         if policy is None:
             return pipe.make_executor(qm or self.quantized, n_i, n_l,
-                                      block_h=block_h,
-                                      interpret=interpret)
+                                      block_h=block_h)
         if x_cal is None:
             raise ValueError("guarded mode needs a calibration input "
                              "(x_cal) to record audit envelopes")
         from . import guard as guard_mod
         return guard_mod.GuardedExecutor(
             self, x_cal, policy=policy, qm=qm, faults=faults,
-            n_i=n_i, n_l=n_l, block_h=block_h, interpret=interpret,
-            checkpoints=checkpoints)
+            n_i=n_i, n_l=n_l, block_h=block_h, checkpoints=checkpoints)
 
     # ------------------------------------------------------ latency model
     def latency_report(self, board: str, n_i: int, n_l: int) -> LatencyReport:

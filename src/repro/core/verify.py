@@ -759,7 +759,7 @@ def _walk_eqns(jaxpr):
     """Yield every eqn reachable from ``jaxpr`` without descending into
     ``pallas_call`` kernels (their body is the kernel's own program —
     the probes reason about what reaches XLA *between* kernels)."""
-    import jax
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
     for eqn in jaxpr.eqns:
@@ -767,9 +767,9 @@ def _walk_eqns(jaxpr):
         if eqn.primitive.name == "pallas_call":
             continue
         for v in eqn.params.values():
-            if isinstance(v, jax.core.ClosedJaxpr):
+            if isinstance(v, ClosedJaxpr):
                 yield from _walk_eqns(v.jaxpr)
-            elif isinstance(v, jax.core.Jaxpr):
+            elif isinstance(v, Jaxpr):
                 yield from _walk_eqns(v)
 
 

@@ -25,7 +25,13 @@ carry rows, so the fused pool stays bit-exact across band boundaries)
 longer scales with the whole Cin (wide VGG/ResNet layers fit deeper
 bands).  The band window *overlaps* its neighbours by the halo, which
 a blocked BlockSpec cannot express; the input spec therefore uses
-unblocked (element-offset) indexing.  Because the input index map
+element-offset (``pl.Element``) indexing.  Strided convs read their
+taps from a *phase-split* band — ``(sh, rows, sw, cols, C)``, one plane
+per stride phase — so every tap is a unit-stride window (Mosaic refuses
+strided value slices); a narrow strided first layer is folded to a
+stride-1 conv over space-to-depth channels instead, where its
+phase-split band would not fit VMEM (:func:`_folds_to_depth`).
+Because the input index map
 ignores the Cout grid axis, the band slice stays resident in VMEM
 while the weight tiles cycle — the old whole-plane kernel re-fetched
 the entire input per Cout tile.  The int32 accumulator lives in
@@ -54,7 +60,7 @@ buffer instead of materializing its own tensor — the channel ``Concat``
 of a GoogLeNet/SqueezeNet branch merge becomes an *output BlockSpec*,
 not a copy.  The buffer rides ``input_output_aliases`` (unwritten
 channels pass through untouched) and every output-side BlockSpec uses
-unblocked element offsets with **clamped** index maps
+element offsets with **clamped** index maps
 (``min(i*tile, size-tile)``): a ragged final row band or Cout tile
 re-computes its overlap with the previous tile — identical values, so
 the revisit is benign — instead of writing padding into neighbouring
@@ -65,6 +71,7 @@ so they commute exactly with the fused max-pool that still runs last).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -87,21 +94,22 @@ _round_shift = ref.round_shift
 
 
 def _band_epilogue(
-    acc,      # (conv_rows * wo, bco) int32 accumulator
+    acc,      # (conv_rows * cols, bco) int32 accumulator
     b_row,    # (1, bco) int32 bias
-    conv_hw: Tuple[int, int],
     shift,                           # int | (1, bco) int32 per-lane row
     relu: bool,
-    pool: Optional[Tuple[int, int]],
-    skip=None,                       # (conv_rows * wo, bco) int8 or None
+    skip=None,                       # (conv_rows * cols, bco) int8 or None
     skip_shifts: Tuple[int, int] = (0, 0),
     merge_shift: int = 0,
     merge_relu: bool = False,
     concat_shift: int = 0,
     concat_relu: bool = False,
 ):
-    """Shared bias/requant/ReLU/max-pool tail of both band kernels —
-    identical fixed-point semantics for dense and depthwise convs.
+    """Shared bias/requant/ReLU tail of both band kernels — identical
+    fixed-point semantics for dense and depthwise convs.  Returns the
+    int8-range result on its int32 carrier; :func:`_band_store` pools
+    and writes it.
+
     With a per-channel quantized layer ``shift`` is a ``(1, bco)``
     int32 row (one count per Cout lane, staged as a kernel operand
     alongside the bias) instead of a static scalar; the merge
@@ -123,8 +131,6 @@ def _band_epilogue(
     so it is skipped) and the merge's fused ReLU — before the pool.
     Both maps are monotone and per-element, so running them pre-pool is
     bit-identical to pooling the concatenated tensor."""
-    ho, wo = conv_hw
-    bco = acc.shape[-1]
     acc = acc + b_row.astype(jnp.int32)          # (1,bco) broadcasts
     acc = _round_shift(acc, shift)
     if relu:
@@ -142,35 +148,91 @@ def _band_epilogue(
         acc = jnp.clip(_round_shift(acc, concat_shift), INT8_MIN, INT8_MAX)
     if concat_relu:
         acc = jnp.maximum(acc, 0)
-    y = acc.astype(jnp.int8).reshape(ho, wo, bco)
+    return acc
 
+
+def _band_store(o_ref, pool_ref, y, conv_hw: Tuple[int, int], wo: int,
+                pool: Optional[Tuple[int, int]]) -> None:
+    """Write the epilogue result ``y`` — ``(rows * cols, lanes)`` int32
+    holding int8 values, ``cols >= wo`` computed columns — into the
+    output block.  A fused max-pool reads its windows back from the
+    ``(ceil(lanes/128), rows * cols, 128)`` int32 ``pool_ref`` scratch
+    with strided loads, one pooled row and one 128-lane chunk at a
+    time: the pool stride lands on the sublane axis, where Mosaic takes
+    strided loads from a 128-lane ref but refuses strided value slices.
+    The max runs on the int32 carrier, which holds exactly the int8
+    values, so the result is bit-identical to pooling the int8 tensor."""
+    rows, cols = conv_hw
+    if pool is None:
+        y = y.astype(jnp.int8).reshape(rows, cols, -1)
+        o_ref[0] = y[:, :wo] if cols != wo else y
+        return
+    pw, ps = pool
+    pho, pwo = (rows - pw) // ps + 1, (wo - pw) // ps + 1
+    lanes = y.shape[-1]
+    for k in range(pool_ref.shape[0]):
+        lo, width = 128 * k, min(128, lanes - 128 * k)
+        pool_ref[k, :, :width] = y[:, lo:lo + width]
+
+        def pooled_row(a, carry, k=k, lo=lo, width=width):
+            row = None
+            for pi in range(pw):
+                for pj in range(pw):
+                    win = pool_ref[k, pl.ds((a * ps + pi) * cols + pj, pwo,
+                                            stride=ps), :]
+                    row = win if row is None else jnp.maximum(row, win)
+            o_ref[0, a, :, lo:lo + width] = row[:, :width].astype(jnp.int8)
+            return carry
+
+        jax.lax.fori_loop(0, pho, pooled_row, 0)
+
+
+def _scratch_shapes(conv_hw: Tuple[int, int], lanes: int,
+                    pool: Optional[Tuple[int, int]]):
+    """Shapes of a band kernel's int32 VMEM scratch: the accumulator,
+    plus the 128-lane pooling buffer of :func:`_band_store` when a pool
+    is fused."""
+    rows, cols = conv_hw
+    shapes = [(rows * cols, lanes)]
     if pool is not None:
-        pw, ps = pool
-        pho, pwo = (ho - pw) // ps + 1, (wo - pw) // ps + 1
-        pooled = jnp.full((pho, pwo, bco), INT8_MIN, jnp.int8)
-        for pi in range(pw):          # static unroll over the pool window
-            for pj in range(pw):
-                win = jax.lax.slice(
-                    y,
-                    (pi, pj, 0),
-                    (pi + (pho - 1) * ps + 1, pj + (pwo - 1) * ps + 1, bco),
-                    (ps, ps, 1),
-                )
-                pooled = jnp.maximum(pooled, win)
-        y = pooled
-    return y
+        shapes.append((-(-lanes // 128), rows * cols, 128))
+    return shapes
+
+
+def _scratch(conv_hw: Tuple[int, int], lanes: int,
+             pool: Optional[Tuple[int, int]]):
+    return [pltpu.VMEM(s, jnp.int32)
+            for s in _scratch_shapes(conv_hw, lanes, pool)]
+
+
+def scratch_bytes(conv_hw: Tuple[int, int], lanes: int,
+                  pool: Optional[Tuple[int, int]]) -> int:
+    """Bytes of the scratch :func:`_scratch` allocates."""
+    return sum(4 * math.prod(s) for s in _scratch_shapes(conv_hw, lanes, pool))
+
+
+def _tap(x_ref, i: int, j: int, strides: Tuple[int, int],
+         conv_hw: Tuple[int, int]):
+    """Tap ``(i, j)`` of a phase-split band ``(1, sh, rows, sw, cols,
+    C)`` as a ``(conv_rows, cols, C)`` load: input row ``r*sh + i`` is
+    phase ``i % sh``, row ``r + i // sh`` — a unit-stride window, read
+    from the ref so that only one tap is live at a time."""
+    sh, sw = strides
+    ho, cols = conv_hw
+    return x_ref[0, i % sh, pl.ds(i // sh, ho), j % sw, pl.ds(j // sw, cols), :]
 
 
 def _qconv_band_kernel(
-    x_ref,    # (1, band_in_rows, Wp, bci) int8 — halo band, Cin slice
+    x_ref,    # (1, sh, band_rows, sw, cols_in, bci) int8 — halo band
     w_ref,    # (KH, KW, bci, bco) int8
     b_ref,    # (1, bco) int32
     *rest,    # [shift_ref (1, bco) int32,]
-              # [skip_ref (1, conv_rows, Wo, bco) int8,]
+              # [skip_ref (1, conv_rows, cols, bco) int8,]
               # [buf_ref (aliased merge buffer, write-only via o_ref),]
-              # o_ref, acc_ref
+              # o_ref, acc_ref[, pool_ref]
     strides: Tuple[int, int],
-    conv_hw: Tuple[int, int],   # conv rows/cols produced by this band
+    conv_hw: Tuple[int, int],   # conv rows/cols computed by this band
+    wo: int,                    # conv output columns (<= computed cols)
     cin_steps: int,
     has_shift_vec: bool,
     has_skip: bool,
@@ -189,41 +251,34 @@ def _qconv_band_kernel(
     skip_ref = rest.pop(0) if has_skip else None
     if has_out_buf:
         rest.pop(0)   # aliased merge buffer: never read in-kernel
-    o_ref, acc_ref = rest
-    x = x_ref[0]                      # (band_in_rows, Wp, bci)
+    o_ref, acc_ref, pool_ref = rest if pool is not None else (*rest, None)
     kh, kw = w_ref.shape[0], w_ref.shape[1]
-    bci = x.shape[-1]
-    ho, wo = conv_hw
-    sh, sw = strides
+    ho, cols = conv_hw
+
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def _accumulate():
         for i in range(kh):          # static unroll: kh*kw MXU matmuls
             for j in range(kw):
-                patch = jax.lax.slice(
-                    x,
-                    (i, j, 0),
-                    (i + (ho - 1) * sh + 1, j + (wo - 1) * sw + 1, bci),
-                    (sh, sw, 1),
-                )                     # (ho, wo, bci) int8
+                patch = _tap(x_ref, i, j, strides, conv_hw)
                 acc_ref[...] += jnp.dot(
-                    patch.reshape(ho * wo, bci),
+                    patch.reshape(ho * cols, patch.shape[-1]),
                     w_ref[i, j],
                     preferred_element_type=jnp.int32,
                 )
 
     def _finish():
-        skip = (skip_ref[0].reshape(ho * wo, -1)
+        skip = (skip_ref[0].reshape(acc_ref.shape[0], -1)
                 if skip_ref is not None else None)
         s = shift_ref[...] if shift_ref is not None else shift
-        o_ref[0] = _band_epilogue(acc_ref[...], b_ref[...], conv_hw,
-                                  s, relu, pool, skip=skip,
-                                  skip_shifts=skip_shifts,
-                                  merge_shift=merge_shift,
-                                  merge_relu=merge_relu,
-                                  concat_shift=concat_shift,
-                                  concat_relu=concat_relu)
+        y = _band_epilogue(acc_ref[...], b_ref[...], s, relu, skip=skip,
+                           skip_shifts=skip_shifts,
+                           merge_shift=merge_shift,
+                           merge_relu=merge_relu,
+                           concat_shift=concat_shift,
+                           concat_relu=concat_relu)
+        _band_store(o_ref, pool_ref, y, conv_hw, wo, pool)
 
     if cin_steps == 1:
         # whole-Cin contraction: straight-line, no per-step conditionals
@@ -238,15 +293,16 @@ def _qconv_band_kernel(
 
 
 def _qdwconv_band_kernel(
-    x_ref,    # (1, band_in_rows, Wp, bc // multiplier) int8 — halo band
+    x_ref,    # (1, sh, band_rows, sw, cols_in, bc // multiplier) int8
     w_ref,    # (KH, KW, bc) int8 — one filter tap per output channel
     b_ref,    # (1, bc) int32
     *rest,    # [shift_ref (1, bc) int32,]
-              # [skip_ref (1, conv_rows, Wo, bc) int8,]
+              # [skip_ref (1, conv_rows, cols, bc) int8,]
               # [buf_ref (aliased merge buffer, write-only via o_ref),]
-              # o_ref, acc_ref
+              # o_ref, acc_ref[, pool_ref]
     strides: Tuple[int, int],
     conv_hw: Tuple[int, int],
+    wo: int,
     has_shift_vec: bool,
     has_skip: bool,
     multiplier: int,
@@ -281,37 +337,148 @@ def _qdwconv_band_kernel(
     skip_ref = rest.pop(0) if has_skip else None
     if has_out_buf:
         rest.pop(0)   # aliased merge buffer: never read in-kernel
-    o_ref, acc_ref = rest
-    x = x_ref[0]                      # (band_in_rows, Wp, bc // m)
-    if multiplier > 1:
-        x = jnp.repeat(x, multiplier, axis=-1)
+    o_ref, acc_ref, pool_ref = rest if pool is not None else (*rest, None)
     kh, kw = w_ref.shape[0], w_ref.shape[1]
-    bc = o_ref.shape[-1]
-    ho, wo = conv_hw
-    sh, sw = strides
+    ho, cols = conv_hw
 
     acc_ref[...] = jnp.zeros_like(acc_ref)
     for i in range(kh):              # static unroll: kh*kw VPU FMAs
         for j in range(kw):
-            patch = jax.lax.slice(
-                x,
-                (i, j, 0),
-                (i + (ho - 1) * sh + 1, j + (wo - 1) * sw + 1, bc),
-                (sh, sw, 1),
-            )                         # (ho, wo, bc) int8
-            acc_ref[...] += (patch.reshape(ho * wo, bc).astype(jnp.int32)
+            patch = _tap(x_ref, i, j, strides, conv_hw)
+            if multiplier > 1:
+                patch = jnp.repeat(patch, multiplier, axis=-1)
+            acc_ref[...] += (patch.reshape(ho * cols, -1).astype(jnp.int32)
                              * w_ref[i, j].astype(jnp.int32))
 
-    skip = (skip_ref[0].reshape(ho * wo, -1)
+    skip = (skip_ref[0].reshape(acc_ref.shape[0], -1)
             if skip_ref is not None else None)
     s = shift_ref[...] if shift_ref is not None else shift
-    o_ref[0] = _band_epilogue(acc_ref[...], b_ref[...], conv_hw,
-                              s, relu, pool, skip=skip,
-                              skip_shifts=skip_shifts,
-                              merge_shift=merge_shift,
-                              merge_relu=merge_relu,
-                              concat_shift=concat_shift,
-                              concat_relu=concat_relu)
+    y = _band_epilogue(acc_ref[...], b_ref[...], s, relu, skip=skip,
+                       skip_shifts=skip_shifts,
+                       merge_shift=merge_shift,
+                       merge_relu=merge_relu,
+                       concat_shift=concat_shift,
+                       concat_relu=concat_relu)
+    _band_store(o_ref, pool_ref, y, conv_hw, wo, pool)
+
+
+def _espec(block_shape, index_map) -> pl.BlockSpec:
+    """BlockSpec whose index map returns *element* offsets.  Mosaic
+    takes element windows on every dim of a spec or on none, so every
+    dim is a ``pl.Element``: the halo row band, the clamped row and
+    Cout offsets of the concat-into path and the concat channel
+    offsets.  An offset on the lane (channel) dim must be a provable
+    multiple of 128 — pass a literal 0 where the tile is the whole dim
+    (:func:`_tile_off`)."""
+    return pl.BlockSpec(tuple(pl.Element(d) for d in block_shape), index_map)
+
+
+def _tile_off(i, tile: int, n_tiles: int):
+    """Element offset of tile ``i``: a literal 0 for a single tile, so
+    that Mosaic can prove the lane alignment of a whole-dim block."""
+    return 0 if n_tiles == 1 else i * tile
+
+
+def _lane_tile(block: Optional[int], size: int) -> int:
+    """Channel tile on the 128-wide lane axis: the whole dim when the
+    block covers it, else the block rounded up to whole lane tiles
+    (Mosaic moves a channel slice of a wider array only in whole lane
+    tiles).  Tiling the exact integer contraction never changes the
+    result."""
+    if block is None or block >= size:
+        return size
+    return min(_rup(block, 128), size)
+
+
+def _out_cols(wo: int, lanes_in: int, lanes_out: int) -> int:
+    """Conv columns a band computes.  Mosaic folds an int8
+    ``(rows, cols, C)`` tap into ``(rows*cols, C)`` only when ``cols``
+    is a multiple of 8 or ``C`` fills whole lane tiles, so narrow-channel
+    layers compute up to 7 extra (discarded) columns."""
+    if wo % 8 == 0 or (lanes_in % 128 == 0 and lanes_out % 128 == 0):
+        return wo
+    return _rup(wo, 8)
+
+
+def _phase_split(x, strides: Tuple[int, int], rows: int, cols: int):
+    """``(N, H, W, C) -> (N, sh, rows, sw, cols, C)`` with input pixel
+    ``(r*sh + a, c*sw + b)`` at ``[:, a, r, b, c]``: every conv tap of a
+    strided conv becomes a unit-stride window of one phase plane.  The
+    input is zero-padded (zero == the symmetric quantization zero) or
+    cropped to ``(sh*rows, sw*cols)``; at stride 1 this is a reshape."""
+    sh, sw = strides
+    n, h, w, c = x.shape
+    hp, wp = sh * rows, sw * cols
+    if hp > h or wp > w:
+        x = jnp.pad(x, ((0, 0), (0, max(0, hp - h)), (0, max(0, wp - w)),
+                        (0, 0)))
+    if x.shape[1] > hp or x.shape[2] > wp:
+        x = x[:, :hp, :wp]
+    if sh == sw == 1:
+        return x.reshape(n, 1, rows, 1, cols, c)
+    return (x.reshape(n, rows, sh, cols, sw, c)
+            .transpose(0, 2, 1, 4, 3, 5))
+
+
+def _folds_to_depth(strides: Tuple[int, int], cin: int) -> bool:
+    """Whether :func:`qconv2d` runs a strided dense conv as a stride-1
+    conv over space-to-depth channels: where the ``sh*sw*Cin`` folded
+    channels fit the one 128-lane tile that a narrower channel dim is
+    padded to in VMEM anyway.  The phase-split band keeps ``Cin`` on the
+    lanes, so for a narrow first layer it is mostly lane padding: at
+    AlexNet's conv_1 (11x11/4, Cin 3) under the default tiles Mosaic
+    needs 18.59M of scoped VMEM for it, over v5e's 16M limit, and
+    refuses the kernel.  Folded, the same band fills its lanes and
+    compiles."""
+    return strides != (1, 1) and strides[0] * strides[1] * cin <= 128
+
+
+def _s2d_shape(hp: int, wp: int, cin: int, kh: int, kw: int,
+               strides: Tuple[int, int]):
+    """``(rows, cols, channels, kh, kw)`` of the space-to-depth conv
+    that :func:`_space_to_depth` folds a strided conv into."""
+    sh, sw = strides
+    kh2, kw2 = -(-kh // sh), -(-kw // sw)
+    return ((hp - kh) // sh + kh2, (wp - kw) // sw + kw2, sh * sw * cin,
+            kh2, kw2)
+
+
+def _space_to_depth(x, w, strides: Tuple[int, int]):
+    """Fold a strided dense conv into a stride-1 one over stride-phase
+    channels: ``x[:, r*sh + a, c*sw + b, ch]`` moves to channel
+    ``(a*sw + b)*C + ch`` of pixel ``(r, c)``, and the filter to
+    ``ceil(kh/sh) x ceil(kw/sw)`` taps whose extra entries are zero
+    (AlexNet's 11x11/4 conv on 3 channels becomes a 3x3 conv on 48).
+    The int32 sum gains only zero terms, so the result is
+    bit-identical.  See :func:`_folds_to_depth` for where it is used."""
+    sh, sw = strides
+    n, hp, wp, c = x.shape
+    kh, kw, _c, cout = w.shape
+    rows, cols, _c2, kh2, kw2 = _s2d_shape(hp, wp, c, kh, kw, strides)
+    x = jnp.pad(x, ((0, 0), (0, max(0, sh * rows - hp)),
+                    (0, max(0, sw * cols - wp)), (0, 0)))
+    x = (x[:, :sh * rows, :sw * cols]
+         .reshape(n, rows, sh, cols, sw, c)
+         .transpose(0, 1, 3, 2, 4, 5)
+         .reshape(n, rows, cols, sh * sw * c))
+    w = jnp.pad(w, ((0, sh * kh2 - kh), (0, sw * kw2 - kw), (0, 0), (0, 0)))
+    w = (w.reshape(kh2, sh, kw2, sw, c, cout)
+         .transpose(0, 2, 1, 3, 4, 5)
+         .reshape(kh2, kw2, sh * sw * c, cout))
+    return x, w
+
+
+def _halo_band(x, strides, kh: int, kw: int, conv_rows: int, cols: int,
+               last_start: int):
+    """Phase-split ``x`` for row bands of ``conv_rows`` conv rows and
+    ``cols`` computed columns whose last band starts at conv row
+    ``last_start``; returns the split input and its band block shape
+    (without the channel dim)."""
+    sh, sw = strides
+    band_rows = conv_rows + (kh - 1) // sh
+    cols_in = cols + (kw - 1) // sw
+    x6 = _phase_split(x, strides, last_start + band_rows, cols_in)
+    return x6, (1, sh, band_rows, sw, cols_in)
 
 
 def band_geometry(block_h: int, kh: int, sh: int,
@@ -361,7 +528,7 @@ def _qconv2d_into(
 
     The buffer has the *exact* merge geometry — no Cout or row padding
     is allowed to leak into it — so output-side tiles use **clamped**
-    unblocked index maps (``min(i*tile, size-tile)``): a ragged final
+    element-offset index maps (``min(i*tile, size-tile)``): a ragged final
     tile re-computes part of its predecessor's rows/channels with
     identical values instead of writing padding.  Unwritten channels
     (the other producers' slices) pass through untouched via
@@ -390,7 +557,7 @@ def _qconv2d_into(
     bco = min(block_cout, cout)
     n_co = -(-cout // bco)
 
-    bci = min(block_cin or cin, cin)
+    bci = _lane_tile(block_cin, cin)
     cinp = _rup(cin, bci)
     cin_steps = cinp // bci
     if cinp > cin:
@@ -398,11 +565,11 @@ def _qconv2d_into(
         w = jnp.pad(w, ((0, 0), (0, 0), (0, cinp - cin), (0, 0)))
 
     bh = min(block_h or default_block_h(oh, wo), oh)
-    conv_rows, band_in_rows, _in_step = band_geometry(bh, kh, sh, pool)
+    conv_rows = band_geometry(bh, kh, sh, pool)[0]
     n_bands = -(-oh // bh)
-    rows_needed = (oh - bh) * ps * sh + band_in_rows
-    if rows_needed > hp:
-        x = jnp.pad(x, ((0, 0), (0, rows_needed - hp), (0, 0), (0, 0)))
+    cols = _out_cols(wo, bci, bco)
+    x, band = _halo_band(x, strides, kh, kw, conv_rows, cols,
+                         (oh - bh) * ps)
 
     def ostart(hi):          # clamped band start (final-output rows)
         return jnp.minimum(hi * bh, oh - bh)
@@ -412,41 +579,35 @@ def _qconv2d_into(
 
     brow = b.reshape(1, cout)
     in_specs = [
-        pl.BlockSpec((1, band_in_rows, wp, bci),
-                     lambda ni, hi, co, ci: (ni, ostart(hi) * ps * sh, 0,
-                                             ci * bci),
-                     indexing_mode=pl.unblocked),
-        pl.BlockSpec((kh, kw, bci, bco),
-                     lambda ni, hi, co, ci: (0, 0, ci * bci, cstart(co)),
-                     indexing_mode=pl.unblocked),
-        pl.BlockSpec((1, bco), lambda ni, hi, co, ci: (0, cstart(co)),
-                     indexing_mode=pl.unblocked),
+        _espec(band + (bci,),
+               lambda ni, hi, co, ci: (ni, 0, ostart(hi) * ps, 0, 0,
+                                       _tile_off(ci, bci, cin_steps))),
+        _espec((kh, kw, bci, bco),
+               lambda ni, hi, co, ci: (0, 0, _tile_off(ci, bci, cin_steps),
+                                       cstart(co))),
+        _espec((1, bco), lambda ni, hi, co, ci: (0, cstart(co))),
     ]
     operands = [x, w, brow]
     if per_channel:
         svec = jnp.asarray(shift, jnp.int32).reshape(1, cout)
         in_specs.append(
-            pl.BlockSpec((1, bco), lambda ni, hi, co, ci: (0, cstart(co)),
-                         indexing_mode=pl.unblocked))
+            _espec((1, bco), lambda ni, hi, co, ci: (0, cstart(co))))
         operands.append(svec)
     if skip is not None:
         assert skip.shape == (n, ho, wo, cout), (skip.shape,
                                                  (n, ho, wo, cout))
         skip_rows = (oh - bh) * ps + conv_rows
-        if skip_rows > ho:
-            skip = jnp.pad(skip, ((0, 0), (0, skip_rows - ho),
-                                  (0, 0), (0, 0)))
+        skip = jnp.pad(skip, ((0, 0), (0, max(0, skip_rows - ho)),
+                              (0, cols - wo), (0, 0)))
         in_specs.append(
-            pl.BlockSpec((1, conv_rows, wo, bco),
-                         lambda ni, hi, co, ci: (ni, ostart(hi) * ps, 0,
-                                                 cstart(co)),
-                         indexing_mode=pl.unblocked))
+            _espec((1, conv_rows, cols, bco),
+                   lambda ni, hi, co, ci: (ni, ostart(hi) * ps, 0,
+                                           cstart(co))))
         operands.append(skip)
 
-    out_spec = pl.BlockSpec(
+    out_spec = _espec(
         (1, bh, ow, bco),
-        lambda ni, hi, co, ci: (ni, ostart(hi), 0, out_off + cstart(co)),
-        indexing_mode=pl.unblocked)
+        lambda ni, hi, co, ci: (ni, ostart(hi), 0, out_off + cstart(co)))
     in_specs.append(out_spec)        # aliased merge buffer (same tiles)
     operands.append(out_buf)
 
@@ -454,7 +615,8 @@ def _qconv2d_into(
         functools.partial(
             _qconv_band_kernel,
             strides=strides,
-            conv_hw=(conv_rows, wo),
+            conv_hw=(conv_rows, cols),
+            wo=wo,
             cin_steps=cin_steps,
             has_shift_vec=per_channel,
             has_skip=skip is not None,
@@ -472,9 +634,9 @@ def _qconv2d_into(
         in_specs=in_specs,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct(out_buf.shape, jnp.int8),
-        scratch_shapes=[pltpu.VMEM((conv_rows * wo, bco), jnp.int32)],
+        scratch_shapes=_scratch((conv_rows, cols), bco, pool),
         input_output_aliases={len(operands) - 1: 0},
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # ragged tiles revisit rows/channels (same values), so the
             # band and Cout axes are not parallel-safe here
             dimension_semantics=("parallel", "arbitrary", "arbitrary",
@@ -530,6 +692,9 @@ def qconv2d(
     shared merge buffer — after this operand's ``concat_shift``
     alignment and the merge's ``concat_relu`` — and the *whole buffer*
     is returned instead of a standalone tensor."""
+    if _folds_to_depth(strides, x.shape[-1]):
+        x, w = _space_to_depth(x, w, strides)
+        strides = (1, 1)
     if out_buf is not None:
         return _qconv2d_into(
             x, w, b, out_buf, strides=strides, shift=shift, relu=relu,
@@ -551,12 +716,13 @@ def qconv2d(
     if per_channel:
         assert len(shift) == cout, (len(shift), cout)
 
-    bco = min(block_cout, _rup(cout, 128))
+    bco = min(_rup(block_cout, 128), _rup(cout, 128))
     coutp = _rup(cout, bco)
+    n_co = coutp // bco
     wpad = jnp.pad(w, ((0, 0), (0, 0), (0, 0), (0, coutp - cout)))
     bpad = jnp.pad(b, (0, coutp - cout)).reshape(1, coutp)
 
-    bci = min(block_cin or cin, cin)
+    bci = _lane_tile(block_cin, cin)
     cinp = _rup(cin, bci)
     cin_steps = cinp // bci
     if cinp > cin:  # zero channels contribute nothing to the dot
@@ -570,22 +736,25 @@ def qconv2d(
         oh, ow = ho, wo
 
     bh = min(block_h or default_block_h(oh, wo), oh)
-    conv_rows, band_in_rows, in_step = band_geometry(bh, kh, sh, pool)
+    conv_rows = band_geometry(bh, kh, sh, pool)[0]
     n_bands = -(-oh // bh)
     ohp = n_bands * bh
+    # conv-row distance between band starts (the input row distance
+    # over the conv stride)
+    conv_step = bh * (pool[1] if pool is not None else 1)
+    cols = _out_cols(wo, bci, bco)
     # Rows past the last valid output row read zero-padding (zero ==
     # symmetric quantization zero-point); their outputs are sliced off.
-    rows_needed = (n_bands - 1) * in_step + band_in_rows
-    if rows_needed > hp:
-        x = jnp.pad(x, ((0, 0), (0, rows_needed - hp), (0, 0), (0, 0)))
+    x, band = _halo_band(x, strides, kh, kw, conv_rows, cols,
+                         (n_bands - 1) * conv_step)
 
     in_specs = [
-        # Overlapping halo bands: element-offset (unblocked) indexing;
-        # the map ignores `co`, so the band slice stays resident across
-        # the Cout tiles (no per-tile input re-read).
-        pl.BlockSpec((1, band_in_rows, wp, bci),
-                     lambda ni, hi, co, ci: (ni, hi * in_step, 0, ci * bci),
-                     indexing_mode=pl.unblocked),
+        # Overlapping halo bands: element-offset indexing; the map
+        # ignores `co`, so the band slice stays resident across the Cout
+        # tiles (no per-tile input re-read).
+        _espec(band + (bci,),
+               lambda ni, hi, co, ci: (ni, 0, hi * conv_step, 0, 0,
+                                       _tile_off(ci, bci, cin_steps))),
         pl.BlockSpec((kh, kw, bci, bco),
                      lambda ni, hi, co, ci: (0, 0, ci, co)),
         pl.BlockSpec((1, bco), lambda ni, hi, co, ci: (0, co)),
@@ -603,24 +772,23 @@ def qconv2d(
         assert skip.shape == (n, ho, wo, cout), (skip.shape, (n, ho, wo, cout))
         # Conv-row band of the residual operand.  Bands of conv rows
         # overlap when a pool is fused (the pool-window carry), so the
-        # skip spec is unblocked too; its rows step by the *conv* row
-        # stride between bands (= in_step / conv stride).
-        conv_step = bh * (pool[1] if pool is not None else 1)
+        # skip spec takes element offsets too; its rows step by the
+        # *conv* row distance between bands.
         skip_rows = (n_bands - 1) * conv_step + conv_rows
         skip = jnp.pad(skip, ((0, 0), (0, max(0, skip_rows - ho)),
-                              (0, 0), (0, coutp - cout)))
+                              (0, cols - wo), (0, coutp - cout)))
         in_specs.append(
-            pl.BlockSpec((1, conv_rows, wo, bco),
-                         lambda ni, hi, co, ci: (ni, hi * conv_step, 0,
-                                                 co * bco),
-                         indexing_mode=pl.unblocked))
+            _espec((1, conv_rows, cols, bco),
+                   lambda ni, hi, co, ci: (ni, hi * conv_step, 0,
+                                           _tile_off(co, bco, n_co))))
         operands.append(skip)
 
     out = pl.pallas_call(
         functools.partial(
             _qconv_band_kernel,
             strides=strides,
-            conv_hw=(conv_rows, wo),
+            conv_hw=(conv_rows, cols),
+            wo=wo,
             cin_steps=cin_steps,
             has_shift_vec=per_channel,
             has_skip=skip is not None,
@@ -631,13 +799,13 @@ def qconv2d(
             merge_shift=merge_shift,
             merge_relu=merge_relu,
         ),
-        grid=(n, n_bands, coutp // bco, cin_steps),
+        grid=(n, n_bands, n_co, cin_steps),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bh, ow, bco),
                                lambda ni, hi, co, ci: (ni, hi, 0, co)),
         out_shape=jax.ShapeDtypeStruct((n, ohp, ow, coutp), jnp.int8),
-        scratch_shapes=[pltpu.VMEM((conv_rows * wo, bco), jnp.int32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        scratch_shapes=_scratch((conv_rows, cols), bco, pool),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,
@@ -704,7 +872,7 @@ def qdwconv2d(
     ps = pool[1] if pool is not None else 1
 
     bh = min(block_h or default_block_h(oh, wo), oh)
-    conv_rows, band_in_rows, in_step = band_geometry(bh, kh, sh, pool)
+    conv_rows = band_geometry(bh, kh, sh, pool)[0]
     n_bands = -(-oh // bh)
     conv_step = bh * ps
 
@@ -717,9 +885,9 @@ def qdwconv2d(
         bc = min(block_c, cout)
         bc = max(bc - bc % m, m)     # whole input channels per tile
         n_c = -(-cout // bc)
-        rows_needed = (oh - bh) * ps * sh + band_in_rows
-        if rows_needed > hp:
-            x = jnp.pad(x, ((0, 0), (0, rows_needed - hp), (0, 0), (0, 0)))
+        cols = _out_cols(wo, bc // m, bc)
+        x, band = _halo_band(x, strides, kh, kw, conv_rows, cols,
+                             (oh - bh) * ps)
 
         def ostart(hi):
             return jnp.minimum(hi * bh, oh - bh)
@@ -731,47 +899,40 @@ def qdwconv2d(
 
         brow = b.reshape(1, cout)
         in_specs = [
-            pl.BlockSpec((1, band_in_rows, wp, bc // m),
-                         lambda ni, hi, ci: (ni, ostart(hi) * ps * sh, 0,
-                                             cstart(ci) // m),
-                         indexing_mode=pl.unblocked),
-            pl.BlockSpec((kh, kw, bc),
-                         lambda ni, hi, ci: (0, 0, cstart(ci)),
-                         indexing_mode=pl.unblocked),
-            pl.BlockSpec((1, bc), lambda ni, hi, ci: (0, cstart(ci)),
-                         indexing_mode=pl.unblocked),
+            _espec(band + (bc // m,),
+                   lambda ni, hi, ci: (ni, 0, ostart(hi) * ps, 0, 0,
+                                       cstart(ci) // m)),
+            _espec((kh, kw, bc), lambda ni, hi, ci: (0, 0, cstart(ci))),
+            _espec((1, bc), lambda ni, hi, ci: (0, cstart(ci))),
         ]
         operands = [x, w, brow]
         if per_channel:
             svec = jnp.asarray(shift, jnp.int32).reshape(1, cout)
             in_specs.append(
-                pl.BlockSpec((1, bc), lambda ni, hi, ci: (0, cstart(ci)),
-                             indexing_mode=pl.unblocked))
+                _espec((1, bc), lambda ni, hi, ci: (0, cstart(ci))))
             operands.append(svec)
         if skip is not None:
             assert skip.shape == (n, ho, wo, cout), (skip.shape,
                                                      (n, ho, wo, cout))
             skip_rows = (oh - bh) * ps + conv_rows
-            if skip_rows > ho:
-                skip = jnp.pad(skip, ((0, 0), (0, skip_rows - ho),
-                                      (0, 0), (0, 0)))
+            skip = jnp.pad(skip, ((0, 0), (0, max(0, skip_rows - ho)),
+                                  (0, cols - wo), (0, 0)))
             in_specs.append(
-                pl.BlockSpec((1, conv_rows, wo, bc),
-                             lambda ni, hi, ci: (ni, ostart(hi) * ps, 0,
-                                                 cstart(ci)),
-                             indexing_mode=pl.unblocked))
+                _espec((1, conv_rows, cols, bc),
+                       lambda ni, hi, ci: (ni, ostart(hi) * ps, 0,
+                                           cstart(ci))))
             operands.append(skip)
-        out_spec = pl.BlockSpec(
+        out_spec = _espec(
             (1, bh, ow, bc),
-            lambda ni, hi, ci: (ni, ostart(hi), 0, out_off + cstart(ci)),
-            indexing_mode=pl.unblocked)
+            lambda ni, hi, ci: (ni, ostart(hi), 0, out_off + cstart(ci)))
         in_specs.append(out_spec)
         operands.append(out_buf)
         return pl.pallas_call(
             functools.partial(
                 _qdwconv_band_kernel,
                 strides=strides,
-                conv_hw=(conv_rows, wo),
+                conv_hw=(conv_rows, cols),
+                wo=wo,
                 has_shift_vec=per_channel,
                 has_skip=skip is not None,
                 has_out_buf=True,
@@ -789,9 +950,9 @@ def qdwconv2d(
             in_specs=in_specs,
             out_specs=out_spec,
             out_shape=jax.ShapeDtypeStruct(out_buf.shape, jnp.int8),
-            scratch_shapes=[pltpu.VMEM((conv_rows * wo, bc), jnp.int32)],
+            scratch_shapes=_scratch((conv_rows, cols), bc, pool),
             input_output_aliases={len(operands) - 1: 0},
-            compiler_params=pltpu.TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary")),
             interpret=interpret,
         )(*operands)
@@ -799,23 +960,23 @@ def qdwconv2d(
     bc = min(block_c, _rup(cout, 128))
     bc = max(bc - bc % m, m)         # whole input channels per tile
     cp = _rup(cout, bc)              # m | bc  =>  m | cp
+    n_c = cp // bc
     if cp > cout:  # zero channels: zero weights/bias keep them inert
         x = jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, cp // m - c_in)))
     wpad = jnp.pad(w, ((0, 0), (0, 0), (0, cp - cout)))
     bpad = jnp.pad(b, (0, cp - cout)).reshape(1, cp)
 
     ohp = n_bands * bh
-    rows_needed = (n_bands - 1) * in_step + band_in_rows
-    if rows_needed > hp:
-        x = jnp.pad(x, ((0, 0), (0, rows_needed - hp), (0, 0), (0, 0)))
+    cols = _out_cols(wo, bc // m, bc)
+    x, band = _halo_band(x, strides, kh, kw, conv_rows, cols,
+                         (n_bands - 1) * conv_step)
 
     in_specs = [
-        # Halo band, channel-tiled: unblocked element offsets (rows
-        # overlap between bands; channels advance by whole tiles).
-        pl.BlockSpec((1, band_in_rows, wp, bc // m),
-                     lambda ni, hi, ci: (ni, hi * in_step, 0,
-                                         ci * (bc // m)),
-                     indexing_mode=pl.unblocked),
+        # Halo band, channel-tiled: element offsets (rows overlap
+        # between bands; channels advance by whole tiles).
+        _espec(band + (bc // m,),
+               lambda ni, hi, ci: (ni, 0, hi * conv_step, 0, 0,
+                                   _tile_off(ci, bc // m, n_c))),
         pl.BlockSpec((kh, kw, bc), lambda ni, hi, ci: (0, 0, ci)),
         pl.BlockSpec((1, bc), lambda ni, hi, ci: (0, ci)),
     ]
@@ -829,23 +990,23 @@ def qdwconv2d(
         assert skip.shape == (n, ho, wo, cout), (skip.shape,
                                                  (n, ho, wo, cout))
         # Conv-row band of the residual operand (see qconv2d): bands of
-        # conv rows overlap when a pool is fused, so unblocked rows
+        # conv rows overlap when a pool is fused, so element-offset rows
         # stepping by the conv row stride; channels pad to the tile grid.
         skip_rows = (n_bands - 1) * conv_step + conv_rows
         skip = jnp.pad(skip, ((0, 0), (0, max(0, skip_rows - ho)),
-                              (0, 0), (0, cp - cout)))
+                              (0, cols - wo), (0, cp - cout)))
         in_specs.append(
-            pl.BlockSpec((1, conv_rows, wo, bc),
-                         lambda ni, hi, ci: (ni, hi * conv_step, 0,
-                                             ci * bc),
-                         indexing_mode=pl.unblocked))
+            _espec((1, conv_rows, cols, bc),
+                   lambda ni, hi, ci: (ni, hi * conv_step, 0,
+                                       _tile_off(ci, bc, n_c))))
         operands.append(skip)
 
     out = pl.pallas_call(
         functools.partial(
             _qdwconv_band_kernel,
             strides=strides,
-            conv_hw=(conv_rows, wo),
+            conv_hw=(conv_rows, cols),
+            wo=wo,
             has_shift_vec=per_channel,
             has_skip=skip is not None,
             multiplier=m,
@@ -856,13 +1017,13 @@ def qdwconv2d(
             merge_shift=merge_shift,
             merge_relu=merge_relu,
         ),
-        grid=(n, n_bands, cp // bc),
+        grid=(n, n_bands, n_c),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bh, ow, bc),
                                lambda ni, hi, ci: (ni, hi, 0, ci)),
         out_shape=jax.ShapeDtypeStruct((n, ohp, ow, cp), jnp.int8),
-        scratch_shapes=[pltpu.VMEM((conv_rows * wo, bc), jnp.int32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        scratch_shapes=_scratch((conv_rows, cols), bc, pool),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
@@ -889,13 +1050,14 @@ def qgconv2d(
 ) -> jnp.ndarray:
     """Ragged grouped conv (1 < groups < Cin, or any group count the
     dense/depthwise kernels don't cover): row-banded Pallas path that
-    puts the *group* on its own grid axis.  Grid is
-    ``(batch, H/block_h, groups)``; each step contracts one group's
-    ``Cin/groups`` input slice against its ``Cout/groups`` filter tile —
-    the dense band kernel body with a single Cin step, so the group
-    tile rides the MXU exactly like a dense Cout tile.  Groups are
-    disjoint in both input and output channels (blocked channel specs;
-    no halo on the channel axis)."""
+    puts the *group* on its own grid axis.  The channels are laid out
+    group-major — group ``g`` of image ``i`` is batch row ``i*groups +
+    g`` — so each grid step ``(batch*groups, H/block_h)`` contracts one
+    group's whole ``Cin/groups`` channel dim against its ``Cout/groups``
+    filter tile: the dense band kernel body with a single Cin step, so
+    the group tile rides the MXU exactly like a dense Cout tile.  (A
+    group's channel slice of the interleaved tensor is narrower than a
+    128-lane tile, which Mosaic cannot move as a block.)"""
     n, hp, wp, cin = x.shape
     kh, kw, cin_g, cout = w.shape
     assert cin == cin_g * groups, (x.shape, w.shape, groups)
@@ -918,35 +1080,40 @@ def qgconv2d(
         oh, ow = ho, wo
 
     bh = min(block_h or default_block_h(oh, wo), oh)
-    conv_rows, band_in_rows, in_step = band_geometry(bh, kh, sh, pool)
+    conv_rows = band_geometry(bh, kh, sh, pool)[0]
     n_bands = -(-oh // bh)
     ohp = n_bands * bh
-    rows_needed = (n_bands - 1) * in_step + band_in_rows
-    if rows_needed > hp:
-        x = jnp.pad(x, ((0, 0), (0, rows_needed - hp), (0, 0), (0, 0)))
+    conv_step = bh * (pool[1] if pool is not None else 1)
+    cols = _out_cols(wo, cin_g, cout_g)
+    xg = (x.reshape(n, hp, wp, groups, cin_g).transpose(0, 3, 1, 2, 4)
+          .reshape(n * groups, hp, wp, cin_g))
+    xg, band = _halo_band(xg, strides, kh, kw, conv_rows, cols,
+                          (n_bands - 1) * conv_step)
 
-    brow = b.reshape(1, cout)
+    def per_group(shape):    # the group's slab of a group-major operand
+        return pl.BlockSpec((pl.Squeezed(),) + shape,
+                            lambda gi, hi: (gi % groups,) + (0,) * len(shape))
+
     in_specs = [
-        # Halo band restricted to one group's input-channel slice.
-        pl.BlockSpec((1, band_in_rows, wp, cin_g),
-                     lambda ni, hi, gi: (ni, hi * in_step, 0, gi * cin_g),
-                     indexing_mode=pl.unblocked),
-        pl.BlockSpec((kh, kw, cin_g, cout_g),
-                     lambda ni, hi, gi: (0, 0, 0, gi)),
-        pl.BlockSpec((1, cout_g), lambda ni, hi, gi: (0, gi)),
+        _espec(band + (cin_g,),
+               lambda gi, hi: (gi, 0, hi * conv_step, 0, 0, 0)),
+        per_group((kh, kw, cin_g, cout_g)),
+        per_group((1, cout_g)),
     ]
-    operands = [x, w, brow]
+    operands = [xg, w.reshape(kh, kw, cin_g, groups, cout_g)
+                .transpose(3, 0, 1, 2, 4),
+                b.reshape(groups, 1, cout_g)]
     if per_channel:
-        svec = jnp.asarray(shift, jnp.int32).reshape(1, cout)
-        in_specs.append(
-            pl.BlockSpec((1, cout_g), lambda ni, hi, gi: (0, gi)))
-        operands.append(svec)
+        in_specs.append(per_group((1, cout_g)))
+        operands.append(jnp.asarray(shift, jnp.int32)
+                        .reshape(groups, 1, cout_g))
 
     out = pl.pallas_call(
         functools.partial(
             _qconv_band_kernel,
             strides=strides,
-            conv_hw=(conv_rows, wo),
+            conv_hw=(conv_rows, cols),
+            wo=wo,
             cin_steps=1,
             has_shift_vec=per_channel,
             has_skip=False,
@@ -957,17 +1124,20 @@ def qgconv2d(
             merge_shift=0,
             merge_relu=False,
         ),
-        grid=(n, n_bands, groups),
+        grid=(n * groups, n_bands),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bh, ow, cout_g),
-                               lambda ni, hi, gi: (ni, hi, 0, gi)),
-        out_shape=jax.ShapeDtypeStruct((n, ohp, ow, cout), jnp.int8),
-        scratch_shapes=[pltpu.VMEM((conv_rows * wo, cout_g), jnp.int32)],
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+                               lambda gi, hi: (gi, hi, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n * groups, ohp, ow, cout_g),
+                                       jnp.int8),
+        scratch_shapes=_scratch((conv_rows, cols), cout_g, pool),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(*operands)
-    return out[:, :oh, :, :]
+    out = (out.reshape(n, groups, ohp, ow, cout_g).transpose(0, 2, 3, 1, 4)
+           .reshape(n, ohp, ow, cout))
+    return out[:, :oh]
 
 
 def band_input_bytes(hp: int, wp: int, cin: int, kh: int, ho: int, *,
@@ -981,7 +1151,7 @@ def band_input_bytes(hp: int, wp: int, cin: int, kh: int, ho: int, *,
     bh = min(block_h or ho, ho)
     _conv_rows, band_in_rows, _step = band_geometry(bh, kh, sh, pool)
     band_in_rows = min(band_in_rows, hp)
-    return band_in_rows * wp * min(block_cin or cin, cin)
+    return band_in_rows * wp * _lane_tile(block_cin, cin)
 
 
 def vmem_bytes(hp: int, wp: int, cin: int, kh: int, kw: int, bco: int,
@@ -995,23 +1165,35 @@ def vmem_bytes(hp: int, wp: int, cin: int, kh: int, kw: int, bco: int,
                per_channel: bool = False) -> int:
     """Per-grid-step working-set estimate used by the DSE resource
     model: one halo row band (one Cin slice of it when ``block_cin`` is
-    set) + weight tile + int32 accumulator scratch + output band, plus
-    the residual skip band (``skip_vmem_bytes``) when a residual add is
-    fused into the epilogue and the int32 per-lane shift row
-    (``shift_vec_bytes``) when the layer is per-channel quantized.
-    ``ho``/``wo`` are *final* output rows/cols (post-pool when ``pool``
-    is fused); ``block_h=None`` means untiled (the whole plane in one
-    band — the old kernel's working set)."""
+    set) + weight tile + int32 scratch + output band, plus the residual
+    skip band (``skip_vmem_bytes``) when a residual add is fused into
+    the epilogue and the int32 per-lane shift row (``shift_vec_bytes``)
+    when the layer is per-channel quantized.  ``ho``/``wo`` are *final*
+    output rows/cols (post-pool when ``pool`` is fused);
+    ``block_h=None`` means untiled (the whole plane in one band — the
+    old kernel's working set).
+
+    The tiles are the ones :func:`qconv2d` builds: a narrow strided
+    conv folded to depth (:func:`_folds_to_depth`), Cin and Cout tiles
+    of whole 128-lane tiles (:func:`_lane_tile`; ``bco`` rounds up the
+    same way), the computed columns of :func:`_out_cols` and the
+    scratch of :func:`scratch_bytes`, fused-pool buffer included."""
+    sw = sw or sh
+    conv_wo = (wp - kw) // sw + 1
+    if _folds_to_depth((sh, sw), cin):
+        hp, wp, cin, kh, kw = _s2d_shape(hp, wp, cin, kh, kw, (sh, sw))
+        sh = sw = 1
     bh = min(block_h or ho, ho)
-    conv_rows, _band_in_rows, _step = band_geometry(bh, kh, sh, pool)
-    bci = min(block_cin or cin, cin)
-    conv_wo = (wp - kw) // (sw or sh) + 1 if pool is not None else wo
+    conv_rows = band_geometry(bh, kh, sh, pool)[0]
+    bci = _lane_tile(block_cin, cin)
+    bco = _rup(bco, 128)
+    cols = _out_cols(conv_wo, bci, bco)
     return (band_input_bytes(hp, wp, cin, kh, ho, sh=sh, block_h=block_h,
                              pool=pool, block_cin=block_cin)  # x band int8
             + kh * kw * bci * bco            # w tile int8
-            + 4 * conv_rows * conv_wo * bco  # acc scratch int32
+            + scratch_bytes((conv_rows, cols), bco, pool)
             + bh * wo * bco                  # y band int8
-            + skip_vmem_bytes(conv_rows, conv_wo, bco, skip)
+            + skip_vmem_bytes(conv_rows, cols, bco, skip)
             + shift_vec_bytes(bco, per_channel))
 
 
@@ -1047,17 +1229,21 @@ def dw_vmem_bytes(wp: int, c: int, kh: int, kw: int, bc: int,
     the *output* channel count; with a channel ``multiplier`` m > 1 the
     input band carries only ``bc / m`` channels (each feeds m output
     lanes in-register), and ``skip`` adds the fused residual band in
-    conv-output geometry, as in :func:`vmem_bytes`."""
+    conv-output geometry, as in :func:`vmem_bytes`.  The channel tile,
+    computed columns and scratch are the ones :func:`qdwconv2d`
+    builds."""
     bh = min(block_h or ho, ho)
     conv_rows, band_in_rows, _step = band_geometry(bh, kh, sh, pool)
-    conv_wo = (wp - kw) // (sw or sh) + 1 if pool is not None else wo
-    bc = min(bc, c)
-    bc_in = -(-bc // multiplier)
+    conv_wo = (wp - kw) // (sw or sh) + 1
+    bc = min(bc, _rup(c, 128))
+    bc = max(bc - bc % multiplier, multiplier)
+    bc_in = bc // multiplier
+    cols = _out_cols(conv_wo, bc_in, bc)
     return (band_in_rows * wp * bc_in        # x band int8 (channel tile)
             + kh * kw * bc                   # per-channel taps int8
-            + 4 * conv_rows * conv_wo * bc   # acc scratch int32
+            + scratch_bytes((conv_rows, cols), bc, pool)
             + bh * wo * bc                   # y band int8
-            + skip_vmem_bytes(conv_rows, conv_wo, bc, skip)
+            + skip_vmem_bytes(conv_rows, cols, bc, skip)
             + shift_vec_bytes(bc, per_channel))
 
 
@@ -1072,13 +1258,14 @@ def gconv_vmem_bytes(wp: int, cin_g: int, cout_g: int, kh: int, kw: int,
     (:func:`qgconv2d`): one group's input-channel slice of the halo
     band, its filter tile, the int32 accumulator, and the group's
     output band — the group axis is a grid axis, so per-step VMEM never
-    scales with the group count."""
+    scales with the group count.  Computed columns and scratch are the
+    ones :func:`qgconv2d` builds."""
     bh = min(block_h or ho, ho)
     conv_rows, band_in_rows, _step = band_geometry(bh, kh, sh, pool)
-    conv_wo = (wp - kw) // (sw or sh) + 1 if pool is not None else wo
+    cols = _out_cols((wp - kw) // (sw or sh) + 1, cin_g, cout_g)
     return (band_in_rows * wp * cin_g        # x band int8 (group slice)
             + kh * kw * cin_g * cout_g       # w tile int8
-            + 4 * conv_rows * conv_wo * cout_g  # acc scratch int32
+            + scratch_bytes((conv_rows, cols), cout_g, pool)
             + bh * wo * cout_g               # y band int8
             + shift_vec_bytes(cout_g, per_channel))
 

@@ -79,7 +79,11 @@ def qgemm(
     per_channel = isinstance(shift, tuple)
     if per_channel:
         assert len(shift) == n, (len(shift), n)
-    bm, bn, bk = min(block_m, _rup(m, 8)), min(block_n, _rup(n, 128)), min(block_k, _rup(k, 128))
+    # N and K ride the 128-wide lane axis of the int8 tiles: whole lane
+    # tiles only (the exact integer contraction is tile-independent)
+    bm = min(block_m, _rup(m, 8))
+    bn = min(_rup(block_n, 128), _rup(n, 128))
+    bk = min(_rup(block_k, 128), _rup(k, 128))
     mp, np_, kp = _rup(m, bm), _rup(n, bn), _rup(k, bk)
     xp = jnp.pad(x, ((0, mp - m), (0, kp - k)))
     wp = jnp.pad(w, ((0, kp - k), (0, np_ - n)))
@@ -109,7 +113,7 @@ def qgemm(
         # accumulator — lets Mosaic double-buffer the K-tile DMAs
         # behind the current tile's matmul (the conv kernels already
         # declare this; the FC kernel was the only one missing it)
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)

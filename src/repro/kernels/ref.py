@@ -63,7 +63,7 @@ def qgemm_ref(
     shift: int,
     relu: bool = False,
 ) -> jnp.ndarray:
-    acc = jnp.dot(x.astype(jnp.int32), w.astype(jnp.int32))
+    acc = jnp.dot(x, w, preferred_element_type=jnp.int32)
     if b is not None:
         acc = acc + b.astype(jnp.int32)[None, :]
     return requant(acc, shift, relu)
@@ -82,14 +82,17 @@ def qconv2d_ref(
     """Fused conv+ReLU+maxpool, NHWC/HWIO, VALID padding (pad upstream).
     ``groups`` follows ONNX Conv semantics (groups == Cin == Cout is
     depthwise); the int32 accumulator is exact, so this is the
-    bit-for-bit oracle for both band kernels and the grouped fallback."""
+    bit-for-bit oracle for both band kernels and the grouped fallback.
+    The int8 operands go in as they are, accumulating in int32: a TPU
+    runs that on its integer matrix unit, where an int32-operand conv
+    is slow or refused."""
     acc = jax.lax.conv_general_dilated(
-        x.astype(jnp.int32),
-        w.astype(jnp.int32),
+        x, w,
         window_strides=strides,
         padding="VALID",
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
         feature_group_count=groups,
+        preferred_element_type=jnp.int32,
     )
     if b is not None:
         acc = acc + b.astype(jnp.int32)[None, None, None, :]

@@ -1,90 +1,42 @@
-"""Production mesh construction + jax version-compat shims.
+"""Production mesh construction and the mesh helpers the launch and
+sharding paths (and the tests) share.
 
 Kept as functions (never module-level constants) so importing this
 module never touches jax device state — required because the dry-run
 must set XLA_FLAGS before any jax initialisation.
-
-This module is also the single home of the jax 0.4.x/0.5+/0.6+ API
-compatibility layer the launch and sharding paths (and the tests) go
-through instead of calling the moving jax surface directly:
-
-  * :func:`make_compat_mesh` — ``jax.make_mesh`` with ``axis_types``
-    passed only when both the ``AxisType`` enum *and* the kwarg exist
-    (``jax.sharding.AxisType`` appeared in jax 0.5; on 0.4.x meshes
-    are implicitly Auto on every axis, which is exactly what passing
-    ``AxisType.Auto`` requests on newer versions);
-  * :func:`set_mesh` — ``jax.set_mesh`` (0.6+) falling back to the
-    legacy ``with mesh:`` resource-env context manager, which is what
-    ``set_mesh`` replaced;
-  * :func:`shard_map` — ``jax.shard_map`` (0.6+, ``check_vma``)
-    falling back to ``jax.experimental.shard_map.shard_map`` (0.4.x,
-    ``check_rep`` — the same flag under its pre-varying-manual-axes
-    name).
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-try:  # jax >= 0.5 explicit-sharding API; absent on older runtimes
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - version-dependent
-    AxisType = None
-
-
-def _make_mesh_accepts_axis_types() -> bool:
-    """``axis_types=`` landed in ``jax.make_mesh`` after the enum
-    itself; inspect the signature so an enum-but-no-kwarg jax never
-    raises TypeError at call time."""
-    try:
-        return "axis_types" in inspect.signature(jax.make_mesh).parameters
-    except (TypeError, ValueError):  # pragma: no cover - builtin/odd repr
-        return False
-
-
-def _axis_type_kwargs(n_axes: int):
-    if AxisType is None or not _make_mesh_accepts_axis_types():
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_compat_mesh(shape, names):
-    """Version-portable ``jax.make_mesh``: explicit Auto axis types on
-    jax versions that have them, plain mesh (implicitly Auto) on 0.4.x.
+    """``jax.make_mesh`` with every axis explicitly Auto-sharded.
     Every mesh the launch path or the test suite builds goes through
-    here — constructing ``AxisType`` directly is what broke the seed
-    suite on jax 0.4.37."""
+    here."""
     shape = tuple(int(s) for s in shape)
     names = tuple(names)
-    return jax.make_mesh(shape, names, **_axis_type_kwargs(len(names)))
+    return jax.make_mesh(shape, names,
+                         axis_types=(AxisType.Auto,) * len(names))
 
 
 def set_mesh(mesh):
-    """Context manager making ``mesh`` the ambient mesh: the modern
-    ``jax.set_mesh`` where it exists, else the legacy resource-env
-    context (``with mesh:``) it replaced."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager making ``mesh`` the ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """Version-portable ``shard_map`` with replication checking off by
-    default (the repo's callers all pass explicit out_specs)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _legacy
-    return _legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check)
+    """``jax.shard_map`` with replication checking off by default (the
+    repo's callers all pass explicit out_specs)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def jit_shardings(mesh, tree):
     """Map a tree of ``PartitionSpec`` leaves onto ``NamedSharding``
-    for ``jax.jit(in_shardings=...)``.  Newer jax resolves bare specs
-    against the ambient mesh; 0.4.x only accepts ``Sharding``
-    instances — explicit ``NamedSharding`` is the portable spelling."""
+    for ``jax.jit(in_shardings=...)``, so that the shardings do not
+    depend on an ambient mesh."""
     from jax.sharding import NamedSharding, PartitionSpec
     return jax.tree.map(
         lambda s: NamedSharding(mesh, s),

@@ -87,8 +87,7 @@ def profile_model(name: str, board: str = "ARRIA10", n_i: int = 16,
     gate.calibrate_quantization(x)
 
     ex = pipe.make_executor(gate.quantized, n_i, n_l, block_h=block_h,
-                            interpret=True, stage_timed=True,
-                            tracer=tracer)
+                            stage_timed=True, tracer=tracer)
     with tracer.span(f"profile.warmup:{name}", cat="profile"):
         for _ in range(max(warmup, 1)):   # compile every sub-closure
             ex(x)

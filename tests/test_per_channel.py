@@ -229,52 +229,7 @@ def test_per_channel_end_to_end_bit_exact_vs_stagewise_oracle(build):
     # oracle replay over the *unfused* program with the same specs
     gate_u = CNN2Gate.from_graph(build(batch=2, in_hw=32), fuse_skip=False)
     gate_u.apply_quantization(gate.specs)
-    qmu = gate_u.quantized
-    h = jnp.clip(jnp.round(xj * 2.0 ** qmu.input_m), -128, 127
-                 ).astype(jnp.int8)
-    h = jnp.transpose(h, (0, 2, 3, 1))
-    env = {qmu.parsed.input_name: h}
-    for ql in qmu.layers:
-        li = ql.info
-        if li.kind == pipe.P.CONV:
-            pool = None
-            if li.pool is not None:
-                pool = (li.pool.kernel_shape[0], li.pool.strides[0])
-            xin = env[li.inputs[0]]
-            if any(li.pads):
-                p = li.pads
-                xin = jnp.pad(xin, ((0, 0), (p[0], p[2]), (p[1], p[3]),
-                                    (0, 0)))
-            wref = ql.w_q
-            if li.is_depthwise:
-                wref = wref.reshape(wref.shape[0], wref.shape[1], 1, -1)
-            env[li.output] = ref.qconv2d_ref(
-                xin, wref, ql.b_q, li.strides, ql.spec.requant_shift,
-                li.relu, pool, groups=li.group)
-        elif li.kind == pipe.P.POOL:
-            fn = (ops.avgpool2d_nhwc if li.pool_type == "avg"
-                  else ops.maxpool2d_nhwc)
-            env[li.output] = fn(env[li.inputs[0]], li.kernel_shape[0],
-                                li.strides[0], li.pads)
-        elif li.kind == pipe.P.FC:
-            hin = env[li.inputs[0]]
-            if hin.ndim > 2:
-                hin = hin.reshape(hin.shape[0], -1)
-            env[li.output] = ref.qgemm_ref(hin, ql.w_q, ql.b_q,
-                                           ql.spec.requant_shift, li.relu)
-        elif li.kind == pipe.P.ADD:
-            env[li.output] = ref.qadd_ref([env[t] for t in li.inputs],
-                                          ql.operand_shifts,
-                                          ql.spec.requant_shift, li.relu)
-        else:
-            raise AssertionError(li.kind)
-    out = env[qmu.parsed.output_name]
-    if out.ndim == 4:
-        out = jnp.transpose(out, (0, 3, 1, 2))
-    want = out.astype(jnp.float32) * (2.0 ** -qmu.output_m)
-    out_stage = qmu.parsed.stage_producing(qmu.parsed.output_name)
-    if out_stage is not None and out_stage.softmax:
-        want = jax.nn.softmax(want, axis=-1)
+    want = pipe.oracle_replay(gate_u.quantized, xj)
     np.testing.assert_array_equal(got, np.asarray(want))
 
 

@@ -8,6 +8,7 @@ drop, and the block_h DSE axis.
 """
 import numpy as np
 import jax
+from jax.extend.core import ClosedJaxpr, Jaxpr
 import jax.numpy as jnp
 import pytest
 
@@ -39,6 +40,10 @@ def i8(*shape):
     (14, 14, 32, 130, 3, 1, (2, 2), 3),   # cout not a multiple of 128
     (11, 11, 8, 16, 3, 2, (2, 2), 1),     # stride-2 conv + pool, 1-row bands
     (18, 18, 4, 24, 3, 1, (2, 2), 100),   # block_h > oh clamps to one band
+    # sh*sw*cin > 128: phase-split band (narrower strided convs fold
+    # to depth)
+    (17, 17, 40, 32, 3, 2, None, 3),      # stride-2, block_h !| oh
+    (20, 20, 36, 16, 3, 2, (2, 2), 2),    # stride-2 conv + pool
 ])
 @pytest.mark.parametrize("shift,relu", [(7, True), (4, False)])
 def test_tiled_qconv_matches_ref(cfg, shift, relu):
@@ -118,9 +123,9 @@ def _count_transposes(jaxpr) -> int:
         if eqn.primitive.name == "pallas_call":
             continue
         for v in eqn.params.values():
-            if isinstance(v, jax.core.ClosedJaxpr):
+            if isinstance(v, ClosedJaxpr):
                 n += _count_transposes(v.jaxpr)
-            elif isinstance(v, jax.core.Jaxpr):
+            elif isinstance(v, Jaxpr):
                 n += _count_transposes(v)
     return n
 
