@@ -587,123 +587,126 @@ def make_executor(qm: QuantizedModel, n_i: int = 16, n_l: int = 32,
         for idx in range(start, stop):
             ql = stages[idx]
             li = ql.info
-            if li.kind == P.CONV:
-                pool = None
-                if li.pool is not None:
-                    pool = (li.pool.kernel_shape[0], li.pool.strides[0])
-                merge_kw = {}
-                if li.merge is not None:  # residual add in the epilogue
-                    merge_kw = dict(
-                        skip=env[li.skip_input],
-                        skip_shifts=ql.operand_shifts,
-                        merge_shift=ql.merge_spec.requant_shift,
-                        merge_relu=li.merge.relu)
-                if li.concat is not None:  # concat merge in the epilogue
-                    cc = li.concat
-                    cq = concat_ql[cc.name]
-                    if cc.pool is not None:  # pool absorbed by the merge
-                        pool = (cc.pool.kernel_shape[0], cc.pool.strides[0])
-                    key = _cbuf_key(cc)
-                    buf = env.get(key)
-                    if buf is None:  # first contributor allocates
-                        _nb, c_, h_, w_ = cc.out_shape
-                        # batch comes from the traced activation, not
-                        # the parse-time shape: the closure must lower
-                        # at any batch (fullflow compiles a sample)
-                        nb = env[li.inputs[0]].shape[0]
-                        buf = jnp.zeros((nb, h_, w_, c_), jnp.int8)
-                    merge_kw.update(
-                        out_buf=buf,
-                        out_off=li.concat_offset,
-                        concat_shift=cq.operand_shifts[
-                            cc.inputs.index(li.output)],
-                        concat_relu=cc.relu)
-                h = ops.qconv2d_nhwc(
-                    env[li.inputs[0]], _w(ql), ql.b_q,
-                    strides=li.strides, pads=li.pads,
-                    shift=ql.spec.requant_shift, relu=li.relu, pool=pool,
-                    groups=li.group, block_cout=block_cout, block_h=block_h,
-                    block_cin=block_cin, interpret=interpret, **merge_kw)
-                if li.concat is not None:
-                    # h IS the shared buffer; the producer's own output
-                    # tensor exists only as a channel slice of it.
-                    # Faults/audit addressing that tensor act on the
-                    # slice (written back via a dynamic update), so the
-                    # resilience layer sees fused and standalone
-                    # programs the same way.
-                    has_static = bool(faults) and li.output in faults
-                    has_arg = li.output in fault_arg_set
-                    if has_static or has_arg or _audited(li.output):
-                        off = li.concat_offset
-                        sl = jax.lax.slice_in_dim(h, off, off + li.c_out,
-                                                  axis=3)
-                        if has_static:
-                            sl = _apply_tensor_faults(sl, faults[li.output])
-                        if has_arg:
-                            sl = _apply_arg_faults(sl, payload[li.output])
-                        if has_static or has_arg:
-                            h = jax.lax.dynamic_update_slice_in_dim(
-                                h, sl, off, axis=3)
-                        if _audited(li.output):
-                            stats[li.output] = _stage_stats(sl)
-                    env[_cbuf_key(li.concat)] = h
-                    for t in li.inputs:  # liveness still applies
-                        if last_use.get(t) == idx:
-                            env.pop(t, None)
-                    continue
-            elif li.kind == P.POOL:
-                pool_fn = (ops.avgpool2d_nhwc if li.pool_type == "avg"
-                           else ops.maxpool2d_nhwc)
-                h = pool_fn(env[li.inputs[0]], li.kernel_shape[0],
-                            li.strides[0], li.pads)
-            elif li.kind == P.FC:
-                h = env[li.inputs[0]]
-                if h.ndim > 2:
-                    # NHWC flatten: rows were permuted at staging time
-                    h = h.reshape(h.shape[0], -1)
-                h = ops.qgemm(h, _w(ql), ql.b_q,
-                              shift=ql.spec.requant_shift,
-                              relu=li.relu,
-                              block_n=min(128, block_cout),
-                              block_k=min(128, block_cin),
-                              interpret=interpret)
-            elif li.kind == P.ADD:
-                h = ops.qadd_nhwc([env[t] for t in li.inputs],
-                                  ql.operand_shifts,
+            # the stage's name on every op it emits (trace-time only)
+            with jax.named_scope(li.name):
+                if li.kind == P.CONV:
+                    pool = None
+                    if li.pool is not None:
+                        pool = (li.pool.kernel_shape[0], li.pool.strides[0])
+                    merge_kw = {}
+                    if li.merge is not None:  # residual add in the epilogue
+                        merge_kw = dict(
+                            skip=env[li.skip_input],
+                            skip_shifts=ql.operand_shifts,
+                            merge_shift=ql.merge_spec.requant_shift,
+                            merge_relu=li.merge.relu)
+                    if li.concat is not None:  # concat merge in the epilogue
+                        cc = li.concat
+                        cq = concat_ql[cc.name]
+                        if cc.pool is not None:  # pool absorbed by the merge
+                            pool = (cc.pool.kernel_shape[0], cc.pool.strides[0])
+                        key = _cbuf_key(cc)
+                        buf = env.get(key)
+                        if buf is None:  # first contributor allocates
+                            _nb, c_, h_, w_ = cc.out_shape
+                            # batch comes from the traced activation, not
+                            # the parse-time shape: the closure must lower
+                            # at any batch (fullflow compiles a sample)
+                            nb = env[li.inputs[0]].shape[0]
+                            buf = jnp.zeros((nb, h_, w_, c_), jnp.int8)
+                        merge_kw.update(
+                            out_buf=buf,
+                            out_off=li.concat_offset,
+                            concat_shift=cq.operand_shifts[
+                                cc.inputs.index(li.output)],
+                            concat_relu=cc.relu)
+                    h = ops.qconv2d_nhwc(
+                        env[li.inputs[0]], _w(ql), ql.b_q,
+                        strides=li.strides, pads=li.pads,
+                        shift=ql.spec.requant_shift, relu=li.relu, pool=pool,
+                        groups=li.group, block_cout=block_cout, block_h=block_h,
+                        block_cin=block_cin, interpret=interpret, **merge_kw)
+                    if li.concat is not None:
+                        # h IS the shared buffer; the producer's own output
+                        # tensor exists only as a channel slice of it.
+                        # Faults/audit addressing that tensor act on the
+                        # slice (written back via a dynamic update), so the
+                        # resilience layer sees fused and standalone
+                        # programs the same way.
+                        has_static = bool(faults) and li.output in faults
+                        has_arg = li.output in fault_arg_set
+                        if has_static or has_arg or _audited(li.output):
+                            off = li.concat_offset
+                            sl = jax.lax.slice_in_dim(h, off, off + li.c_out,
+                                                      axis=3)
+                            if has_static:
+                                sl = _apply_tensor_faults(sl, faults[li.output])
+                            if has_arg:
+                                sl = _apply_arg_faults(sl, payload[li.output])
+                            if has_static or has_arg:
+                                h = jax.lax.dynamic_update_slice_in_dim(
+                                    h, sl, off, axis=3)
+                            if _audited(li.output):
+                                stats[li.output] = _stage_stats(sl)
+                        env[_cbuf_key(li.concat)] = h
+                        for t in li.inputs:  # liveness still applies
+                            if last_use.get(t) == idx:
+                                env.pop(t, None)
+                        continue
+                elif li.kind == P.POOL:
+                    pool_fn = (ops.avgpool2d_nhwc if li.pool_type == "avg"
+                               else ops.maxpool2d_nhwc)
+                    h = pool_fn(env[li.inputs[0]], li.kernel_shape[0],
+                                li.strides[0], li.pads)
+                elif li.kind == P.FC:
+                    h = env[li.inputs[0]]
+                    if h.ndim > 2:
+                        # NHWC flatten: rows were permuted at staging time
+                        h = h.reshape(h.shape[0], -1)
+                    h = ops.qgemm(h, _w(ql), ql.b_q,
                                   shift=ql.spec.requant_shift,
-                                  relu=li.relu)
-            elif li.kind == P.CONCAT:
-                if li.concat_fused:
-                    # the producers already wrote (aligned + relu'd +
-                    # pooled) channel slices in place: the shared buffer
-                    # IS the merge tensor — just unwrap and release it
-                    h = env.pop(_cbuf_key(li))
-                else:
-                    xs = [env[t] for t in li.inputs]
-                    h = ops.qconcat_nhwc(
-                        xs, ql.operand_shifts,
-                        axis=_concat_axis(li.axis, xs[0].ndim),
-                        relu=li.relu)
-            else:  # pragma: no cover - parser only emits the five kinds
-                raise ValueError(li.kind)
-            if faults and li.output in faults:
-                h = _apply_tensor_faults(h, faults[li.output])
-            if li.output in fault_arg_set:
-                h = _apply_arg_faults(h, payload[li.output])
-            if _audited(li.output):
-                stats[li.output] = _stage_stats(h)
-            env[li.output] = h
-            for t in li.inputs:     # liveness-based buffer release
-                if last_use.get(t) == idx:
-                    env.pop(t, None)  # pop: an operand may repeat (x + x)
-            if idx in ckpt_set:
-                # snapshot AFTER the liveness release: the environment
-                # holds exactly the live set — what a replay from this
-                # boundary needs, and nothing more
-                ckpts[li.name] = dict(env)
+                                  relu=li.relu,
+                                  block_n=min(128, block_cout),
+                                  block_k=min(128, block_cin),
+                                  interpret=interpret)
+                elif li.kind == P.ADD:
+                    h = ops.qadd_nhwc([env[t] for t in li.inputs],
+                                      ql.operand_shifts,
+                                      shift=ql.spec.requant_shift,
+                                      relu=li.relu)
+                elif li.kind == P.CONCAT:
+                    if li.concat_fused:
+                        # the producers already wrote (aligned + relu'd +
+                        # pooled) channel slices in place: the shared buffer
+                        # IS the merge tensor — just unwrap and release it
+                        h = env.pop(_cbuf_key(li))
+                    else:
+                        xs = [env[t] for t in li.inputs]
+                        h = ops.qconcat_nhwc(
+                            xs, ql.operand_shifts,
+                            axis=_concat_axis(li.axis, xs[0].ndim),
+                            relu=li.relu)
+                else:  # pragma: no cover - parser only emits the five kinds
+                    raise ValueError(li.kind)
+                if faults and li.output in faults:
+                    h = _apply_tensor_faults(h, faults[li.output])
+                if li.output in fault_arg_set:
+                    h = _apply_arg_faults(h, payload[li.output])
+                if _audited(li.output):
+                    stats[li.output] = _stage_stats(h)
+                env[li.output] = h
+                for t in li.inputs:     # liveness-based buffer release
+                    if last_use.get(t) == idx:
+                        env.pop(t, None)  # pop: an operand may repeat (x + x)
+                if idx in ckpt_set:
+                    # snapshot AFTER the liveness release: the environment
+                    # holds exactly the live set — what a replay from this
+                    # boundary needs, and nothing more
+                    ckpts[li.name] = dict(env)
 
     def _egress(env: Dict[str, jnp.ndarray]) -> jnp.ndarray:
-        return _dequantize_output(qm, env[out_name])
+        with jax.named_scope("egress"):
+            return _dequantize_output(qm, env[out_name])
 
     def _run(env: Dict[str, jnp.ndarray], weights, payload, start: int):
         stats: Dict[str, jnp.ndarray] = {}
@@ -713,12 +716,13 @@ def make_executor(qm: QuantizedModel, n_i: int = 16, n_l: int = 32,
         return _egress(env), stats, ckpts
 
     def _ingress(x_float: jnp.ndarray, payload) -> jnp.ndarray:
-        h = _quantize_input(qm, x_float)
-        if faults and in_name in faults:
-            h = _apply_tensor_faults(h, faults[in_name])
-        if in_name in fault_arg_set:
-            h = _apply_arg_faults(h, payload[in_name])
-        return h
+        with jax.named_scope("ingress"):
+            h = _quantize_input(qm, x_float)
+            if faults and in_name in faults:
+                h = _apply_tensor_faults(h, faults[in_name])
+            if in_name in fault_arg_set:
+                h = _apply_arg_faults(h, payload[in_name])
+            return h
 
     if stage_timed:
         return _make_stage_timed(qm, stages, in_name, _ingress,

@@ -23,7 +23,6 @@ Modes (kernels run in Pallas interpret mode only off a TPU, as
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Callable, Dict, List, Optional
 
 import jax
@@ -34,6 +33,7 @@ from repro.models.cnn import collect_activations
 from . import dse as dse_mod
 from . import parser as P
 from . import pipeline as pipe
+from . import telemetry as tele
 from .graph import Graph
 from .quantize import (MAX_SHIFT, QuantSpec, best_pow2_exponent,
                        best_pow2_exponents_per_channel)
@@ -146,9 +146,21 @@ class CNN2Gate:
         the ``m_y <= m_w + m_x`` non-negative-shift cap simply uses
         the *minimum* lane exponent (every lane's shift must stay
         representable).  Per-tensor calibration is the default.
+
+        On the default tracer the call is the span ``cnn2gate.calibrate``,
+        with the float pass (``cnn2gate.calibrate.float_pass``) and the
+        quantization of the weights (``cnn2gate.calibrate.quantize``) as
+        its children.
         """
+        tracer = tele.get_tracer()
+        with tracer.span("cnn2gate.calibrate", cat="cnn2gate"):
+            return self._calibrate(tracer, sample_input, per_channel)
+
+    def _calibrate(self, tracer: tele.Tracer, sample_input: np.ndarray,
+                   per_channel: bool) -> Dict[str, QuantSpec]:
         pm = self.parsed
-        acts = collect_activations(pm.graph, sample_input)
+        with tracer.span("cnn2gate.calibrate.float_pass", cat="cnn2gate"):
+            acts = collect_activations(pm.graph, sample_input)
         acts[pm.input_name] = np.asarray(sample_input)
         weights = pm.graph.initializers
 
@@ -240,7 +252,8 @@ class CNN2Gate:
                     m_y = m_common
                 specs[li.name] = QuantSpec(m_w=0, m_x=m_common, m_y=m_y)
                 tensor_m[li.output] = m_y
-        self.apply_quantization(specs)
+        with tracer.span("cnn2gate.calibrate.quantize", cat="cnn2gate"):
+            self.apply_quantization(specs)
         return specs
 
     # ---------------------------------------------------------------- DSE
@@ -310,9 +323,13 @@ class CNN2Gate:
         emulation: the jitted executor, compiled at its first call.
         fullflow : AOT-compiled at the graph's input shape before it
         returns (the TPU-target synthesis path; identical numerics);
-        ``synthesis_time_s`` is that compile.  jit's executable cache
-        holds the result, so a call at that shape compiles nothing
-        more; another batch compiles at its first call.
+        ``synthesis_time_s`` is that lowering and compile.  jit's
+        executable cache holds the result, so a call at that shape
+        compiles nothing more; another batch compiles at its first call.
+        On the default tracer a fullflow build is the span
+        ``cnn2gate.build``, with ``cnn2gate.build.lower`` (tracing and
+        lowering the executor) and ``cnn2gate.build.compile`` (the
+        compile, or its load from the persistent cache) as children.
         """
         if self.quantized is None:
             raise RuntimeError("apply_quantization() or "
@@ -321,12 +338,19 @@ class CNN2Gate:
         if mode == "emulation":
             return pipe.make_executor(qm, n_i, n_l, block_h=block_h)
         if mode == "fullflow":
-            jitted = pipe.make_executor(qm, n_i, n_l, block_h=block_h)
-            sample = jax.ShapeDtypeStruct(tuple(self.parsed.input_shape),
-                                          jnp.float32)
-            t0 = time.perf_counter()
-            compiled = jitted.lower(sample).compile()  # the "synthesis"
-            self.synthesis_time_s = time.perf_counter() - t0
+            tracer = tele.get_tracer()
+            with tracer.span("cnn2gate.build", cat="cnn2gate"):
+                jitted = pipe.make_executor(qm, n_i, n_l, block_h=block_h)
+                sample = jax.ShapeDtypeStruct(
+                    tuple(self.parsed.input_shape), jnp.float32)
+                # the "synthesis"
+                with tracer.span("cnn2gate.build.lower",
+                                 cat="cnn2gate") as lower:
+                    lowered = jitted.lower(sample)
+                with tracer.span("cnn2gate.build.compile",
+                                 cat="cnn2gate") as comp:
+                    compiled = lowered.compile()
+            self.synthesis_time_s = (lower.dur_us + comp.dur_us) / 1e6
             self.compiled = compiled
             return jitted
         raise ValueError(f"unknown mode {mode!r}")

@@ -17,11 +17,18 @@ modeled numbers into audited ones:
     thread (Perfetto infers nesting from containment on one track).
     Spans measured elsewhere (the stage-timed executor's
     ``block_until_ready`` wall times) are injected via
-    :meth:`Tracer.add_span`.
+    :meth:`Tracer.add_span`.  A live :meth:`Tracer.span` also enters a
+    ``jax.profiler.TraceAnnotation`` of its name, so that under
+    ``jax.profiler`` it lands on the profile's host line, on the same
+    clock as the device's events.
+  * :func:`watch_gc` — the Python collector's pauses, one histogram
+    sample each, with the newest pauses and their times kept.
 
 Dependency-free on purpose: the stdlib (``threading``, ``time``,
 ``json``) is the whole footprint, so the int8 runtime, the DSE sweeps
-and the serving loop can all afford always-on telemetry.
+and the serving loop can all afford always-on telemetry.  A span
+imports JAX's profiler annotation when it opens, and goes without it
+where JAX is not installed.
 
 Module-level defaults (:func:`get_registry` / :func:`get_tracer`) give
 the instrumented consumers a shared sink without threading a handle
@@ -30,16 +37,19 @@ their own instances or call :func:`reset`.
 """
 from __future__ import annotations
 
+import collections
+import contextlib
+import gc
 import json
 import os
 import threading
 import time
 from contextlib import contextmanager
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Deque, Dict, List, Optional, Sequence, Tuple
 
 __all__ = [
-    "Counter", "Gauge", "Histogram", "MetricsRegistry", "Tracer",
-    "get_registry", "get_tracer", "reset",
+    "Counter", "Gauge", "Histogram", "MetricsRegistry", "Span", "Tracer",
+    "GcWatch", "get_registry", "get_tracer", "reset", "watch_gc",
     "DEFAULT_LATENCY_BUCKETS_S",
 ]
 
@@ -220,6 +230,28 @@ class MetricsRegistry:
             self._metrics.clear()
 
 
+class Span:
+    """What :meth:`Tracer.span` yields: its name, and its start and
+    duration in microseconds on the tracer's clock (``dur_us`` is None
+    until the block ends)."""
+    __slots__ = ("name", "ts_us", "dur_us")
+
+    def __init__(self, name: str):
+        self.name = name
+        self.ts_us: Optional[float] = None
+        self.dur_us: Optional[float] = None
+
+
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` named ``name``, or a null
+    context where JAX is not installed."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return contextlib.nullcontext()
+    return TraceAnnotation(name)
+
+
 class Tracer:
     """Span recorder exporting the Chrome trace-event format.
 
@@ -266,22 +298,30 @@ class Tracer:
     @contextmanager
     def span(self, name: str, cat: str = "",
              args: Optional[Dict] = None):
-        """Time a block and record it as one complete event.  The span
-        is recorded even when the block raises (with ``error`` in its
-        args) — a failed DSE evaluation still shows up in the trace."""
-        t0 = self.now_us()
+        """Time a block and record it as one complete event; yields a
+        :class:`Span` whose ``dur_us`` is set when the block ends.  The
+        span is recorded even when the block raises (with ``error`` in
+        its args) — a failed DSE evaluation still shows up in the trace.
+
+        The block also runs inside a ``jax.profiler.TraceAnnotation``
+        of the same name: while a profile is being taken the span is on
+        its host line; otherwise the annotation is inactive."""
+        rec = Span(name)
         err: Optional[str] = None
-        try:
-            yield self
-        except BaseException as e:
-            err = f"{type(e).__name__}: {e}"
-            raise
-        finally:
-            a = dict(args) if args else {}
-            if err is not None:
-                a["error"] = err
-            self.add_span(name, t0, self.now_us() - t0, cat=cat,
-                          args=a or None)
+        with _annotation(name):
+            rec.ts_us = t0 = self.now_us()
+            try:
+                yield rec
+            except BaseException as e:
+                err = f"{type(e).__name__}: {e}"
+                raise
+            finally:
+                rec.dur_us = self.now_us() - t0
+                a = dict(args) if args else {}
+                if err is not None:
+                    a["error"] = err
+                self.add_span(name, t0, rec.dur_us, cat=cat,
+                              args=a or None)
 
     def events(self) -> List[Dict]:
         with self._lock:
@@ -305,6 +345,59 @@ class Tracer:
             self._events.clear()
             self.dropped = 0
             self._epoch = time.perf_counter()
+
+
+# -------------------------------------------------- collector pauses
+
+#: histogram of the collector's pauses (:func:`watch_gc`)
+GC_PAUSE = "process.gc_pause_s"
+#: buckets of :data:`GC_PAUSE`: 10 µs to 10 s
+GC_PAUSE_BUCKETS_S: Tuple[float, ...] = (
+    1e-5, 3e-5, 1e-4, 3e-4, 1e-3, 3e-3, 1e-2, 3e-2, 0.1, 0.3, 1.0, 3.0,
+    10.0)
+
+
+class GcWatch:
+    """A running :func:`watch_gc`: ``pauses`` holds the newest
+    ``(start, seconds, generation)`` of each pass, start on
+    ``time.perf_counter``'s clock; :meth:`stop` takes the callback
+    away."""
+
+    def __init__(self, hist: Histogram, keep: int):
+        self.pauses: Deque[Tuple[float, float, int]] = collections.deque(
+            maxlen=keep)
+        self._hist = hist
+        self._t0: Optional[float] = None
+
+    def __call__(self, phase: str, info: Dict) -> None:
+        now = time.perf_counter()
+        if phase == "start":
+            self._t0 = now
+        elif self._t0 is not None:
+            d = now - self._t0
+            self._hist.record(d)
+            self.pauses.append((self._t0, d, int(info.get("generation", -1))))
+            self._t0 = None
+
+    def longest(self, n: int = 5) -> List[Tuple[float, float, int]]:
+        """The ``n`` longest pauses kept, longest first."""
+        return sorted(self.pauses, key=lambda p: -p[1])[:n]
+
+    def stop(self) -> None:
+        if self in gc.callbacks:
+            gc.callbacks.remove(self)
+
+
+def watch_gc(registry: Optional[MetricsRegistry] = None,
+             keep: int = 4096) -> GcWatch:
+    """Record every pass of the Python collector from now on: its pause
+    into the histogram :data:`GC_PAUSE` of ``registry`` (the default
+    one if None) and, with its start, into the returned watch's
+    ``pauses``.  ``watch.stop()`` ends it."""
+    reg = registry if registry is not None else get_registry()
+    w = GcWatch(reg.histogram(GC_PAUSE, GC_PAUSE_BUCKETS_S), keep)
+    gc.callbacks.append(w)
+    return w
 
 
 # ------------------------------------------------- module-level defaults

@@ -2,6 +2,8 @@
 conventions, Chrome-trace schema validity, span nesting, the stage-timed
 executor's parity/coverage, and the jaxpr-identity guarantee that
 telemetry never perturbs the default executor."""
+import gc
+import glob
 import json
 import threading
 
@@ -170,6 +172,96 @@ def test_tracer_export(tmp_path):
     with open(path) as f:
         doc = json.load(f)
     assert doc["traceEvents"][0]["name"] == "s"
+
+
+def test_span_yields_its_timing():
+    tr = tele.Tracer()
+    with tr.span("s") as sp:
+        assert sp.name == "s" and sp.dur_us is None
+    (ev,) = tr.events()
+    assert sp.ts_us == ev["ts"] and sp.dur_us == ev["dur"] >= 0
+
+
+def test_span_lands_on_the_profilers_host_plane(tmp_path):
+    """Under jax.profiler a span is also an annotation of the same name
+    on the profile's host plane, on the device events' clock."""
+    tr = tele.Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("cnn2gate.test.outer"):
+            with tr.span("cnn2gate.test.inner"):
+                jax.numpy.ones(8).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = {e.name: e for plane in jax.profiler.ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:") for line in plane.lines
+            for e in line.events}
+    outer, inner = host["cnn2gate.test.outer"], host["cnn2gate.test.inner"]
+    assert outer.start_ns <= inner.start_ns
+    assert inner.start_ns + inner.duration_ns <= outer.start_ns + outer.duration_ns
+    assert [e["name"] for e in tr.events()] == ["cnn2gate.test.inner",
+                                                "cnn2gate.test.outer"]
+
+
+def test_watch_gc_records_each_pass_until_stopped():
+    reg = tele.MetricsRegistry()
+    w = tele.watch_gc(reg, keep=4)
+    try:
+        for _ in range(6):
+            gc.collect()
+    finally:
+        w.stop()
+    h = reg.histogram(tele.GC_PAUSE, tele.GC_PAUSE_BUCKETS_S)
+    assert h.count >= 6 and len(w.pauses) == 4
+    assert all(d >= 0 and g in (0, 1, 2) for _, d, g in w.pauses)
+    assert [p[1] for p in w.longest(2)] == sorted((p[1] for p in w.pauses),
+                                                  reverse=True)[:2]
+    n = h.count
+    gc.collect()
+    assert h.count == n and w not in gc.callbacks
+
+
+# ------------------------------------------------- set-up spans and scopes
+
+def test_calibrate_and_build_record_their_parts():
+    tr = tele.get_tracer()
+    tr.reset()
+    try:
+        g = CNN2Gate.from_graph(cnn.tiny_cnn(batch=1))
+        x = RNG.standard_normal((1, 3, 32, 32)).astype(np.float32)
+        g.calibrate_quantization(x)
+        g.build("fullflow")
+        ev = {e["name"]: e for e in tr.events()}
+    finally:
+        tele.reset()
+    for parent, children in (("cnn2gate.calibrate", ("float_pass", "quantize")),
+                             ("cnn2gate.build", ("lower", "compile"))):
+        p = ev[parent]
+        for c in children:
+            e = ev[f"{parent}.{c}"]
+            assert p["ts"] <= e["ts"] and e["ts"] + e["dur"] <= p["ts"] + p["dur"]
+    assert g.synthesis_time_s == pytest.approx(
+        (ev["cnn2gate.build.lower"]["dur"] + ev["cnn2gate.build.compile"]["dur"]) / 1e6)
+
+
+TINY_STAGES = [li.name for li in CNN2Gate.from_graph(cnn.tiny_cnn(batch=1)).parsed.layers]
+
+
+@pytest.fixture(scope="module")
+def tiny_lowered_text():
+    g = CNN2Gate.from_graph(cnn.tiny_cnn(batch=1))
+    x = RNG.standard_normal((1, 3, 32, 32)).astype(np.float32)
+    g.calibrate_quantization(x)
+    ex = pipe.make_executor(g.quantized, 16, 32, interpret=True)
+    return ex.lower(jax.ShapeDtypeStruct(x.shape, np.float32)).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("stage", TINY_STAGES + ["ingress", "egress"])
+def test_every_stage_names_its_ops(tiny_lowered_text, stage):
+    """Each stage's ops carry its name in their location (the op_name a
+    device trace shows), under the executor's own scope."""
+    assert f"jit(forward)/{stage}/" in tiny_lowered_text
 
 
 # ----------------------------------------------- stage-timed executor
