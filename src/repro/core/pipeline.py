@@ -38,7 +38,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
+from repro.kernels.qgemm import fc_tiles
 from . import parser as P
+from . import telemetry
 from . import verify as V
 from .quantize import INT8_MAX, INT8_MIN, QuantSpec, quantize_weights
 
@@ -378,6 +380,18 @@ def _dequantize_output(qm: QuantizedModel, h: jnp.ndarray) -> jnp.ndarray:
     return logits
 
 
+def _record_fc_tiles(stage: str, m: int, k: int, n: int) -> None:
+    """The FC stage's blocks and grid steps as ``cnn2gate.fc.<stage>.*``
+    gauges on the default registry.  Called while the executor traces,
+    so once per compile and never per request."""
+    t = fc_tiles(m, k, n)
+    reg = telemetry.get_registry()
+    for key, v in (("block_m", t.bm), ("block_k", t.bk), ("block_n", t.bn),
+                   ("grid_steps", t.grid_steps),
+                   ("weight_block_bytes", t.weight_block_bytes)):
+        reg.gauge(f"cnn2gate.fc.{stage}.{key}").set(v)
+
+
 def make_executor(qm: QuantizedModel, n_i: int = 16, n_l: int = 32,
                   block_h: Optional[int] = None,
                   interpret: Optional[bool] = None,
@@ -396,15 +410,18 @@ def make_executor(qm: QuantizedModel, n_i: int = 16, n_l: int = 32,
     ``x_float`` is the NCHW float input; the result is float logits
     (dequantized with the output tensor's m).
 
-    (N_i, N_l, block_h) select kernel tile shapes: N_l lanes ->
-    output-channel tile (x8: eight 8-bit MACs per lane-vector element
+    (N_i, N_l, block_h) select the conv kernels' tile shapes: N_l lanes
+    -> output-channel tile (x8: eight 8-bit MACs per lane-vector element
     feed one MXU row), N_i -> ``block_cin = 8*N_i`` input-channel
-    contraction tile (the conv kernel's innermost grid axis and the FC
-    kernel's K tile — a real blocking knob, not just an analytical
-    report), block_h -> the conv kernel's row-band height (the
-    line-buffer depth of DESIGN.md §2).  Functionally the result is
-    identical for every option — options trade resources for speed,
-    exactly as in the paper.
+    contraction tile (the conv kernel's innermost grid axis — a real
+    blocking knob, not just an analytical report), block_h -> the conv
+    kernel's row-band height (the line-buffer depth of DESIGN.md §2).
+    The FC kernel's tiles follow from each layer's shape
+    (``qgemm.fc_tiles``: multi-MiB weight blocks, since an FC layer at
+    small batch only streams its weights); tracing records them on the
+    default telemetry registry as ``cnn2gate.fc.<stage>.*`` gauges.
+    Functionally the result is identical for every option — options
+    trade resources for speed, exactly as in the paper.
 
     Conv stages with a folded residual add (``li.merge``) feed the skip
     operand straight into the kernel epilogue — no standalone add stage
@@ -663,12 +680,11 @@ def make_executor(qm: QuantizedModel, n_i: int = 16, n_l: int = 32,
                     if h.ndim > 2:
                         # NHWC flatten: rows were permuted at staging time
                         h = h.reshape(h.shape[0], -1)
-                    h = ops.qgemm(h, _w(ql), ql.b_q,
+                    w = _w(ql)
+                    _record_fc_tiles(li.name, h.shape[0], *w.shape)
+                    h = ops.qgemm(h, w, ql.b_q,
                                   shift=ql.spec.requant_shift,
-                                  relu=li.relu,
-                                  block_n=min(128, block_cout),
-                                  block_k=min(128, block_cin),
-                                  interpret=interpret)
+                                  relu=li.relu, interpret=interpret)
                 elif li.kind == P.ADD:
                     h = ops.qadd_nhwc([env[t] for t in li.inputs],
                                       ql.operand_shifts,

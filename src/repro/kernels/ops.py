@@ -35,10 +35,11 @@ def default_interpret() -> bool:
 
 
 def qgemm(x, w, b=None, *, shift, relu: bool = False,
-          block_m: int = 128, block_n: int = 128, block_k: int = 128,
-          interpret: Optional[bool] = None):
+          block_m: Optional[int] = None, block_n: Optional[int] = None,
+          block_k: Optional[int] = None, interpret: Optional[bool] = None):
     """``shift`` is an int (per-tensor) or a length-N tuple (per-output-
-    channel weight scales — the per-lane shift vector path)."""
+    channel weight scales — the per-lane shift vector path); a block left
+    at None is chosen from the shape (``qgemm.fc_tiles``)."""
     interpret = default_interpret() if interpret is None else interpret
     return _qgemm.qgemm(x, w, b, shift=shift, relu=relu, block_m=block_m,
                         block_n=block_n, block_k=block_k, interpret=interpret)

@@ -8,8 +8,9 @@ layer shape of the paper's AlexNet and VGG-16 at 224x224 with the
 executor's default tiles (fused pools included), the Cin tile at every
 ``8*N_i`` the DSE can choose, ResNet-18's strided convs (the
 phase-split band: AlexNet's narrow strided conv_1 is folded to depth),
-the first FC of VGG-16, and the depthwise, grouped and concat-into band
-kernels at a ``*_tiny`` shape.
+every FC shape of both at batch 1 and 32 (with the weights kept in
+HBM, also under ``vmap``), and the depthwise, grouped and concat-into
+band kernels at a ``*_tiny`` shape.
 
 The topology is described inside a fixture, never while a module is
 imported: only one process may hold the TPU library, and with several
@@ -18,6 +19,7 @@ test workers each of them imports this file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -171,13 +173,58 @@ def test_conv_compiles_at_every_dse_cin_tile(one_chip, layer, n_i):
     assert len(got) == 1 and got == default
 
 
-def test_qgemm_vgg16_fc1_compiles_for_v5e(one_chip):
-    """VGG-16's first FC at batch 1: K = 25088, N = 4096."""
-    k, n = 25088, 4096
+#: (K, N) of every FC layer of ``alexnet()`` and ``vgg16()``
+FC_SHAPES = [(25088, 4096), (9216, 4096), (4096, 4096), (4096, 1000)]
+
+
+@pytest.mark.parametrize("m", [1, 32])
+@pytest.mark.parametrize("k,n", FC_SHAPES,
+                         ids=[f"{k}x{n}" for k, n in FC_SHAPES])
+def test_qgemm_vgg16_fc1_compiles_for_v5e(one_chip, m, k, n):
+    """Every FC shape of AlexNet and VGG-16 at batch 1 and 32 with the
+    tiles chosen from the shape: multi-MiB weight blocks, which Mosaic
+    refuses if they overrun the scoped VMEM."""
     _compile(lambda x, w, b: ops.qgemm(x, w, b, shift=7, relu=True,
                                        interpret=False),
-             _sds((1, k), jnp.int8, one_chip),
+             _sds((m, k), jnp.int8, one_chip),
              _sds((k, n), jnp.int8, one_chip),
+             _sds((n,), jnp.int32, one_chip))
+
+
+def _operand_layouts(hlo: str, target: str = "tpu_custom_call"):
+    """Type and layout of each operand of the first custom call to
+    ``target`` in a compiled module's text."""
+    call = next(ln for ln in hlo.splitlines() if f'"{target}"' in ln)
+    names = re.search(r"custom-call\(([^)]*)\)", call).group(1).split(", ")
+    return [re.search(rf"^\s*(?:ROOT )?{re.escape(nm)} = (\S+)", hlo,
+                      re.M).group(1) for nm in names]
+
+
+def test_qgemm_weights_stream_from_hbm(one_chip):
+    """The weight operand stays in HBM (no ``S(1)``, VMEM, in its
+    layout): XLA stages a free 4 MiB weight (the last FC of both
+    networks) into VMEM ahead of the kernel, and the kernel's device
+    time would leave its stream out."""
+    k, n = 4096, 1000
+    compiled = _compile(
+        lambda x, w, b: ops.qgemm(x, w, b, shift=7, relu=True,
+                                  interpret=False),
+        _sds((1, k), jnp.int8, one_chip), _sds((k, n), jnp.int8, one_chip),
+        _sds((n,), jnp.int32, one_chip))
+    w_layout = _operand_layouts(compiled.as_text())[1]
+    assert w_layout.startswith("s8[") and "S(1)" not in w_layout, w_layout
+
+
+def test_qgemm_compiles_over_a_batch_of_weight_images(one_chip):
+    """Fault trials vmap weight images through one executor
+    (core/ser.py); the HBM constraint has no batching rule, so the batch
+    goes to the kernel without it."""
+    k, n = 4096, 1000
+    _compile(jax.vmap(lambda x, w, b: ops.qgemm(x, w, b, shift=7, relu=True,
+                                                interpret=False),
+                      in_axes=(None, 0, None)),
+             _sds((1, k), jnp.int8, one_chip),
+             _sds((3, k, n), jnp.int8, one_chip),
              _sds((n,), jnp.int32, one_chip))
 
 

@@ -4,7 +4,9 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels import ops, ref
-from repro.kernels.qgemm import qgemm
+from repro.core.resources import VMEM_BUDGET_BYTES
+from repro.kernels.qgemm import (BLOCK_VMEM_BYTES, WEIGHT_BLOCK_BYTES,
+                                 fc_tiles, fc_vmem_bytes, qgemm)
 from repro.kernels.qconv import qconv2d
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ssd_scan import ssd_scan
@@ -27,6 +29,56 @@ def test_qgemm_matches_ref(m, k, n, shift, relu):
                 block_m=32, block_n=128, block_k=128)
     want = ref.qgemm_ref(x, w, b, shift, relu)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("m", [1, 32])
+@pytest.mark.parametrize("k", [300, 9216])    # 128-rounded; 6 K blocks
+@pytest.mark.parametrize("per_channel", [False, True])
+@pytest.mark.parametrize("relu", [False, True])
+def test_qgemm_default_tiles_match_ref(m, k, per_channel, relu):
+    """The tiles chosen from the shape, N = 1000 padded to 1024."""
+    n = 1000
+    x, w = i8(m, k), i8(k, n)
+    b = jnp.asarray(RNG.integers(-(1 << 20), 1 << 20, (n,), np.int32))
+    shift = (tuple(int(s) for s in RNG.integers(4, 14, n)) if per_channel
+             else 9)
+    got = qgemm(x, w, b, shift=shift, relu=relu, interpret=True)
+    want = ref.qgemm_ref(x, w, b, shift, relu)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("m", [1, 8, 32, 128, 200])
+@pytest.mark.parametrize("k,n", [(25088, 4096), (9216, 4096), (4096, 4096),
+                                 (4096, 1000), (512, 10), (300, 130),
+                                 (49, 100000)])
+def test_fc_tiles_rule(m, k, n):
+    """Whole lane tiles dividing the 128-rounded K and N (no weight pads
+    further), double-buffered blocks and the accumulator within the
+    scoped VMEM, and weight blocks within their budget."""
+    t = fc_tiles(m, k, n)
+    kp0, np0 = -(-k // 128) * 128, -(-n // 128) * 128
+    assert t.bk % 128 == 0 and t.bn % 128 == 0
+    assert (t.kp, t.np_) == (kp0, np0)
+    assert kp0 % t.bk == 0 and np0 % t.bn == 0
+    assert t.bm % 8 == 0 and t.mp >= m and t.mp % t.bm == 0
+    assert t.weight_block_bytes <= WEIGHT_BLOCK_BYTES
+    assert fc_vmem_bytes(t.bm, t.bk, t.bn) <= BLOCK_VMEM_BYTES \
+        <= VMEM_BUDGET_BYTES
+    # as deep as the budgets allow: the next lane divisor of K breaks one
+    deeper = [d for d in range(t.bk + 128, kp0 + 1, 128) if kp0 % d == 0]
+    if deeper:
+        d = deeper[0]
+        assert (d * t.bn > WEIGHT_BLOCK_BYTES
+                or fc_vmem_bytes(t.bm, d, t.bn) > BLOCK_VMEM_BYTES)
+
+
+def test_fc_tiles_stream_vgg16_fc1_in_few_steps():
+    t = fc_tiles(1, 25088, 4096)
+    assert t.grid_steps <= 64
+    assert (t.bm, t.bk, t.bn, t.grid_steps) == (8, 512, 4096, 49)
+    assert fc_tiles(1, 9216, 4096).grid_steps == 18
+    assert fc_tiles(1, 4096, 4096).grid_steps == 8
+    assert fc_tiles(1, 4096, 1000).grid_steps == 2
 
 
 def test_qgemm_no_bias():

@@ -245,6 +245,30 @@ def test_calibrate_and_build_record_their_parts():
         (ev["cnn2gate.build.lower"]["dur"] + ev["cnn2gate.build.compile"]["dur"]) / 1e6)
 
 
+def test_build_records_each_fc_stages_tiles():
+    """Each FC stage's blocks and grid steps, as ``fc_tiles`` chose them
+    for the traced batch, are gauges on the default registry."""
+    from repro.kernels.qgemm import fc_tiles
+    tele.reset()
+    try:
+        g = CNN2Gate.from_graph(cnn.tiny_cnn(batch=2))
+        x = RNG.standard_normal((2, 3, 32, 32)).astype(np.float32)
+        g.calibrate_quantization(x)
+        g.build("fullflow")
+        gauges = tele.get_registry().snapshot()["gauges"]
+    finally:
+        tele.reset()
+    fcs = [ql for ql in g.quantized.layers if ql.info.kind == pipe.P.FC]
+    assert fcs
+    for ql in fcs:
+        t = fc_tiles(2, *ql.w_q.shape)
+        pre = f"cnn2gate.fc.{ql.info.name}."
+        assert gauges[pre + "grid_steps"] == t.grid_steps
+        assert gauges[pre + "weight_block_bytes"] == t.weight_block_bytes
+        assert (gauges[pre + "block_m"], gauges[pre + "block_k"],
+                gauges[pre + "block_n"]) == (t.bm, t.bk, t.bn)
+
+
 TINY_STAGES = [li.name for li in CNN2Gate.from_graph(cnn.tiny_cnn(batch=1)).parsed.layers]
 
 
