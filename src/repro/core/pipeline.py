@@ -392,6 +392,22 @@ def _record_fc_tiles(stage: str, m: int, k: int, n: int) -> None:
         reg.gauge(f"cnn2gate.fc.{stage}.{key}").set(v)
 
 
+def _record_graph_counts(pm: P.ParsedModel) -> None:
+    """The parsed graph's residual adds, those folded into a conv's
+    epilogue, and its windowed pools that run as their own op (a head's
+    global average pool is not one) as ``cnn2gate.graph.*`` gauges on
+    the default registry.  Called while the executor traces."""
+    op_type = {n.name: n.op_type for n in pm.graph.nodes}
+    fused = sum(li.merge is not None for li in pm.layers)
+    reg = telemetry.get_registry()
+    reg.gauge("cnn2gate.graph.adds").set(
+        fused + sum(li.kind == P.ADD for li in pm.layers))
+    reg.gauge("cnn2gate.graph.adds_fused").set(fused)
+    reg.gauge("cnn2gate.graph.pools_standalone").set(sum(
+        li.kind == P.POOL and op_type[li.name] != "GlobalAveragePool"
+        for li in pm.layers))
+
+
 def make_executor(qm: QuantizedModel, n_i: int = 16, n_l: int = 32,
                   block_h: Optional[int] = None,
                   interpret: Optional[bool] = None,
@@ -419,7 +435,9 @@ def make_executor(qm: QuantizedModel, n_i: int = 16, n_l: int = 32,
     The FC kernel's tiles follow from each layer's shape
     (``qgemm.fc_tiles``: multi-MiB weight blocks, since an FC layer at
     small batch only streams its weights); tracing records them on the
-    default telemetry registry as ``cnn2gate.fc.<stage>.*`` gauges.
+    default telemetry registry as ``cnn2gate.fc.<stage>.*`` gauges,
+    beside the graph's ``cnn2gate.graph.*`` counts of residual adds and
+    standalone pools.
     Functionally the result is identical for every option — options
     trade resources for speed, exactly as in the paper.
 
@@ -753,6 +771,7 @@ def make_executor(qm: QuantizedModel, n_i: int = 16, n_l: int = 32,
         return jax.jit(replay)
 
     def forward(x_float: jnp.ndarray, *extra):
+        _record_graph_counts(qm.parsed)
         weights, payload = _extra(extra)
         h = _ingress(x_float, payload)
         env: Dict[str, jnp.ndarray] = {in_name: h}

@@ -234,18 +234,37 @@ def tiny_cnn_gap(batch: int = 1, num_classes: int = 10, seed: int = 0,
     return b.build()
 
 
+def _residual_merge(b: GraphBuilder, skip: Tuple[str, Tuple[int, ...]],
+                    stride: int) -> None:
+    """Close a residual block whose main path ends at the current tensor:
+    identity skip, or a 1x1 projection (ResNet option B) drawn after the
+    main path where the stride or the width changes, then add and ReLU."""
+    main = b.tap()
+    c_out = main[1][1]
+    if stride != 1 or skip[1][1] != c_out:
+        b.from_tap(skip).conv(c_out, 1, stride=stride, relu=False)
+        skip = b.tap()
+    b.from_tap(main).add_from(skip, relu=True)
+
+
 def _basic_block(b: GraphBuilder, c_out: int, stride: int = 1) -> None:
     """ResNet basic block: two 3x3 convs + identity/projection skip,
     post-add ReLU (the canonical v1 ordering)."""
     skip = b.tap()
     b.conv(c_out, 3, stride=stride, pad=1)
     b.conv(c_out, 3, pad=1, relu=False)
-    main = b.tap()
-    if stride != 1 or skip[1][1] != c_out:
-        # 1x1 strided projection on the skip path (ResNet option B)
-        b.from_tap(skip).conv(c_out, 1, stride=stride, relu=False)
-        skip = b.tap()
-    b.from_tap(main).add_from(skip, relu=True)
+    _residual_merge(b, skip, stride)
+
+
+def _bottleneck_block(b: GraphBuilder, width: int, stride: int = 1) -> None:
+    """ResNet v1.5 bottleneck block: 1x1 reduce to ``width``, 3x3 at the
+    block's stride (v1.5 strides here, not in the first 1x1), 1x1 expand
+    to ``4 * width`` without ReLU, then the skip, add and ReLU."""
+    skip = b.tap()
+    b.conv(width, 1)
+    b.conv(width, 3, stride=stride, pad=1)
+    b.conv(4 * width, 1, relu=False)
+    _residual_merge(b, skip, stride)
 
 
 def resnet_tiny(batch: int = 1, num_classes: int = 10, seed: int = 0,
@@ -274,6 +293,25 @@ def resnet18(batch: int = 1, num_classes: int = 1000, seed: int = 0,
     for c_out, stride in ((64, 1), (64, 1), (128, 2), (128, 1),
                           (256, 2), (256, 1), (512, 2), (512, 1)):
         _basic_block(b, c_out, stride)
+    b.global_avgpool()
+    b.fc(num_classes, relu=False, softmax=True)
+    return b.build()
+
+
+def resnet50(batch: int = 1, num_classes: int = 1000, seed: int = 0,
+             in_hw: int = 224) -> Graph:
+    """ResNet-50 v1.5 [He et al., Table 1, 50-layer column; the stride
+    in each downsampling block's 3x3 conv, as torchvision and MLPerf
+    Inference run it]: 7x7/2 stem + padded 3x3/2 max-pool, four
+    bottleneck stages (width 64/128/256/512 with 3/4/6/3 blocks, a 1x1
+    projection on each stage's first block), GAP head.  53 convs, 16
+    residual adds.  ``in_hw`` shrinks the input for interpret-mode
+    tests."""
+    b = GraphBuilder("resnet50", (batch, 3, in_hw, in_hw), seed)
+    b.conv(64, 7, stride=2, pad=3).maxpool(3, 2, pad=1)
+    for width, blocks, stride in ((64, 3, 1), (128, 4, 2), (256, 6, 2), (512, 3, 2)):
+        for i in range(blocks):
+            _bottleneck_block(b, width, stride if i == 0 else 1)
     b.global_avgpool()
     b.fc(num_classes, relu=False, softmax=True)
     return b.build()

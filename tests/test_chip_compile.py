@@ -8,7 +8,9 @@ layer shape of the paper's AlexNet and VGG-16 at 224x224 with the
 executor's default tiles (fused pools included), the Cin tile at every
 ``8*N_i`` the DSE can choose, ResNet-18's strided convs (the
 phase-split band: AlexNet's narrow strided conv_1 is folded to depth),
-every FC shape of both at batch 1 and 32 (with the weights kept in
+ResNet-50's distinct bottleneck geometries (1x1 convs with the residual
+add in their epilogue at Cout up to 2048, the 1x1/2 projections, the
+v1.5 3x3/2 convs), every FC shape of both at batch 1 and 32 (with the weights kept in
 HBM, also under ``vmap``), and the depthwise, grouped and concat-into
 band kernels at a ``*_tiny`` shape.
 
@@ -64,6 +66,23 @@ VGG16_CONVS = _vgg16_convs()
 RESNET18_STRIDED = [
     ("block3-conv3x3s2", 56, 64, 128, 3, 2, 1, None),
     ("block3-proj1x1s2", 56, 64, 128, 1, 2, 0, None),
+]
+
+#: ``resnet50()``'s conv geometries at 224x224 that no chain has, with
+#: the skip epilogue where the parser folds a residual add into the conv:
+#: each stage's 1x1 expand, its 1x1 projection (stride 2 from stage 2 on,
+#: the host of the block's add there) and its v1.5 3x3/2 conv
+RESNET50_CONVS = [
+    ("expand56-1x1-skip", 56, 64, 256, 1, 1, 0, None, True),
+    ("expand28-1x1-skip", 28, 128, 512, 1, 1, 0, None, True),
+    ("expand14-1x1-skip", 14, 256, 1024, 1, 1, 0, None, True),
+    ("expand7-1x1-skip", 7, 512, 2048, 1, 1, 0, None, True),
+    ("proj56-1x1s2-skip", 56, 256, 512, 1, 2, 0, None, True),
+    ("proj28-1x1s2-skip", 28, 512, 1024, 1, 2, 0, None, True),
+    ("proj14-1x1s2-skip", 14, 1024, 2048, 1, 2, 0, None, True),
+    ("conv56-3x3s2", 56, 128, 128, 3, 2, 1, None),
+    ("conv28-3x3s2", 28, 256, 256, 3, 2, 1, None),
+    ("conv14-3x3s2", 14, 512, 512, 3, 2, 1, None),
 ]
 
 #: the executor's default tiles: ``make_executor(n_i=16, n_l=32)``
@@ -124,18 +143,28 @@ def _pallas_calls(fn, *shapes):
     return found
 
 
-def _compile_conv(sharding, hw, cin, cout, k, stride, pad, pool,
+def _compile_conv(sharding, hw, cin, cout, k, stride, pad, pool, skip=False,
                   block_cin=DEFAULT_BLOCK_CIN, groups=1, depthwise=False,
                   out_buf=None, out_off=0, kernels_only=False):
+    """``skip``: a residual add folded into the epilogue, its int8
+    operand of the output's shape."""
     w_shape = (k, k, 1 if depthwise else cin // groups, cout)
     shapes = [_sds((1, hw, hw, cin), jnp.int8, sharding),
               _sds(w_shape, jnp.int8, sharding),
               _sds((cout,), jnp.int32, sharding)]
     if out_buf is not None:
         shapes.append(_sds(out_buf, jnp.int8, sharding))
+    if skip:
+        ho = (hw + 2 * pad - k) // stride + 1
+        shapes.append(_sds((1, ho, ho, cout), jnp.int8, sharding))
 
-    def conv(x, w, b, *buf):
-        kw = dict(out_buf=buf[0], out_off=out_off) if buf else {}
+    def conv(x, w, b, *extra):
+        kw = {}
+        if out_buf is not None:
+            kw.update(out_buf=extra[0], out_off=out_off)
+        if skip:
+            kw.update(skip=extra[-1], skip_shifts=(1, 0), merge_shift=1,
+                      merge_relu=True)
         return ops.qconv2d_nhwc(
             x, w, b, strides=(stride, stride), pads=(pad,) * 4, shift=7,
             relu=True, pool=pool, groups=cin if depthwise else groups,
@@ -148,10 +177,11 @@ def _compile_conv(sharding, hw, cin, cout, k, stride, pad, pool,
 
 
 @pytest.mark.parametrize(
-    "layer", ALEXNET_CONVS + VGG16_CONVS + RESNET18_STRIDED,
+    "layer", ALEXNET_CONVS + VGG16_CONVS + RESNET18_STRIDED + RESNET50_CONVS,
     ids=[f"alexnet-{c[0]}" for c in ALEXNET_CONVS]
     + [f"vgg16-{c[0]}" for c in VGG16_CONVS]
-    + [f"resnet18-{c[0]}" for c in RESNET18_STRIDED])
+    + [f"resnet18-{c[0]}" for c in RESNET18_STRIDED]
+    + [f"resnet50-{c[0]}" for c in RESNET50_CONVS])
 def test_conv_layer_compiles_for_v5e(one_chip, layer):
     _name, *geometry = layer
     _compile_conv(one_chip, *geometry)

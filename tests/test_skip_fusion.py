@@ -136,6 +136,21 @@ def test_resnet_tiny_folds_every_add():
         assert li.merge_intermediate not in [l.output for l in pm.layers]
 
 
+@pytest.mark.parametrize("build,adds", [(cnn.resnet18, 8), (cnn.resnet50, 16)])
+def test_every_residual_add_folds_at_224(build, adds):
+    """At the published 224x224 every merge of the full-size resnets
+    folds into a conv epilogue, parsing only: ResNet-50's 12 identity
+    merges are hosted by a stride-1 1x1 expand, its 4 projection merges
+    by the 1x1 projection."""
+    g = build()
+    assert sum(n.op_type == "Add" for n in g.nodes) == adds
+    pm = P.parse(g)
+    assert not any(li.kind == P.ADD for li in pm.layers)
+    hosts = [li for li in pm.layers if li.merge is not None]
+    assert len(hosts) == adds
+    assert all(li.merge.relu and li.skip_input in li.inputs for li in hosts)
+
+
 def test_projection_block_host_is_later_conv():
     """When both Add operands are single-consumer convs (projection
     block), the later-scheduled conv hosts so the skip is already
@@ -266,11 +281,12 @@ def test_softmax_on_add_blocks_fold():
 @pytest.mark.parametrize("build,in_hw", [
     (cnn.resnet_tiny, 32),
     (cnn.resnet18, 32),
+    (cnn.resnet50, 32),
 ])
 def test_fused_program_bit_exact_vs_unfused(build, in_hw):
     """Acceptance: the skip-fused executor is bit-exact against the
-    unfused Conv -> Add program under the same specs, on both resnet
-    builders."""
+    unfused Conv -> Add program under the same specs, on every resnet
+    builder."""
     kw = dict(batch=2, in_hw=in_hw)
     gate_f = CNN2Gate.from_graph(build(**kw))
     x = (RNG.standard_normal((2, 3, in_hw, in_hw)) * 0.5

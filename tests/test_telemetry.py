@@ -269,6 +269,27 @@ def test_build_records_each_fc_stages_tiles():
                 gauges[pre + "block_n"]) == (t.bm, t.bk, t.bn)
 
 
+@pytest.mark.parametrize("build,adds,pools", [(cnn.resnet50, 16, 1), (cnn.resnet_tiny, 2, 0)])
+def test_build_records_the_graphs_adds_and_pools(build, adds, pools):
+    """Tracing the executor sets ``cnn2gate.graph.*`` gauges: the
+    residual adds, those folded into a conv epilogue (all of them in
+    both resnets), and the windowed pools that run as their own op
+    (ResNet-50's padded stem max-pool; neither head's global average
+    pool counts)."""
+    tele.reset()
+    try:
+        g = CNN2Gate.from_graph(build(batch=1, in_hw=32))
+        g.calibrate_quantization(RNG.standard_normal((1, 3, 32, 32)).astype(np.float32))
+        pipe.make_executor(g.quantized, interpret=True).lower(
+            jax.ShapeDtypeStruct((1, 3, 32, 32), np.float32))
+        gauges = tele.get_registry().snapshot()["gauges"]
+    finally:
+        tele.reset()
+    assert gauges["cnn2gate.graph.adds"] == adds
+    assert gauges["cnn2gate.graph.adds_fused"] == adds
+    assert gauges["cnn2gate.graph.pools_standalone"] == pools
+
+
 TINY_STAGES = [li.name for li in CNN2Gate.from_graph(cnn.tiny_cnn(batch=1)).parsed.layers]
 
 
